@@ -108,8 +108,8 @@ def _real_rows(n_req: int, n_new: int) -> list[dict]:
             # fresh paged state, warm jit caches
             execu.alloc = BlockAllocator(256, 16)
             assert execu.alloc.extend(-1, 16) == [0]       # trash page
-            execu.k_pages = jnp.zeros_like(execu.k_pages)
-            execu.v_pages = jnp.zeros_like(execu.v_pages)
+            execu.k_pages = jax.tree.map(jnp.zeros_like, execu.k_pages)
+            execu.v_pages = jax.tree.map(jnp.zeros_like, execu.v_pages)
             execu.n_dispatches = 0
             eng = Engine(make_scheduler(
                 "fairbatching", LinearCostModel(1e-4, 1e-6, 1e-10)),

@@ -8,7 +8,7 @@ a short context does wasted work a finer tile would have skipped).
 
 On this repo's CI backend the Pallas kernel cannot execute compiled, so the
 tuner is *analytic*, built on the repo's existing cost machinery: roofline
-constants from ``benchmarks.roofline_report`` (PEAK_FLOPS / HBM_BW) price
+TPU v5e peaks from ``benchmarks.roofline_report`` (``V5E``) price
 the compute and HBM terms, ``repro.launch.hlo_analysis.shape_bytes`` prices
 each VMEM tile from its HLO shape string, and the kernel's exact host-side
 skip predicate (same arithmetic as the ``pl.when`` guard) is evaluated over
@@ -32,7 +32,7 @@ import dataclasses
 import json
 import os
 
-from .roofline_report import HBM_BW, PEAK_FLOPS
+from .roofline_report import V5E
 
 # per-grid-step issue overhead (index maps, DMA descriptors, predicate):
 # dominates when tiles are tiny, which is exactly what the tuner must
@@ -135,7 +135,7 @@ def _cost(geom: KernelGeom, seqs, t_bucket: int, pg_bucket: int,
     grid_steps = geom.n_kv_heads * n_qb * n_seq * n_pb
     flops *= geom.n_kv_heads
     bytes_acc *= geom.n_kv_heads
-    return (max(flops / PEAK_FLOPS, bytes_acc / HBM_BW)
+    return (max(flops / V5E["bf16_flops"], bytes_acc / V5E["hbm_bytes_per_s"])
             + GRID_STEP_OVERHEAD_S * grid_steps)
 
 

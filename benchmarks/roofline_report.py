@@ -21,9 +21,33 @@ import os
 
 from repro import configs
 
-PEAK_FLOPS = 197e12          # bf16 / chip (TPU v5e-class)
-HBM_BW = 819e9               # B/s per chip
-ICI_BW = 50e9                # B/s per link
+# Published per-chip peaks keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (system architecture table):
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+# of chip-to-chip interconnect over 4 ICI links.
+CHIP_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes": 16e9,
+        "hbm_bytes_per_s": 819e9,
+        "ici_link_bytes_per_s": 1600e9 / 8 / 4,
+    },
+}
+# the chip every modeled number in this repo is priced for
+V5E = CHIP_PEAKS["TPU v5 lite"]
+
+
+def device_peaks(device=None) -> dict:
+    """Published peaks of ``device`` (default: the first JAX device). A
+    kind missing from ``CHIP_PEAKS`` is an error, never a default."""
+    import jax
+
+    kind = (device or jax.devices()[0]).device_kind
+    if kind not in CHIP_PEAKS:
+        raise KeyError(f"no published peaks for device kind {kind!r}; add "
+                       "them to CHIP_PEAKS with their source")
+    return CHIP_PEAKS[kind]
 
 _COLL_FACTOR = {"all-gather": 1.0, "all-reduce": 2.0, "reduce-scatter": 1.0,
                 "all-to-all": 1.0, "collective-permute": 1.0,
@@ -86,18 +110,18 @@ def analyze_record(rec: dict) -> dict:
     coll = rec.get("collectives_corrected", {})
     coll_bytes = sum(v["bytes"] * _COLL_FACTOR.get(k, 1.0)
                      for k, v in coll.items())
-    t_c = fl / PEAK_FLOPS
-    t_m_hlo = by / HBM_BW
-    t_m_min = analytic_min_bytes_per_device(rec) / HBM_BW
+    t_c = fl / V5E["bf16_flops"]
+    t_m_hlo = by / V5E["hbm_bytes_per_s"]
+    t_m_min = analytic_min_bytes_per_device(rec) / V5E["hbm_bytes_per_s"]
     # memory term: analytic floor (HLO bytes from the CPU lowering carry
     # f32-artifact + fusion-operand overcounts; both reported)
     t_m = t_m_min
-    t_x = coll_bytes / ICI_BW
+    t_x = coll_bytes / V5E["ici_link_bytes_per_s"]
     dom = max((t_c, "compute"), (t_m, "memory"), (t_x, "collective"))[1]
     mf = model_flops_per_device(rec)
     bound = max(t_c, t_m, t_x)
     # achievable bound for this cell = the larger of ideal compute & memory
-    ideal = max(mf / PEAK_FLOPS, t_m_min)
+    ideal = max(mf / V5E["bf16_flops"], t_m_min)
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
         "compute_s": t_c, "memory_s": t_m, "memory_s_hlo": t_m_hlo,

@@ -238,6 +238,8 @@ def main() -> None:
     ap.add_argument("--json-out", default="experiments/bench_results.json")
     args = ap.parse_args()
     quick = not args.full
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     from . import (async_pipeline_bench, autotune_attention, breakdown_bench,
                    chaos_bench, cluster_bench, cost_model_bench, disagg_bench,

@@ -37,7 +37,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 import dataclasses  # noqa: E402
 import statistics  # noqa: E402
 
-from .roofline_report import HBM_BW, PEAK_FLOPS
+from .roofline_report import V5E
 
 # fixed per-step launch/host overhead (seconds): the `a` of the roofline-
 # derived cost model. Paid once per step on EVERY shard — the term TP can
@@ -62,9 +62,9 @@ def _roofline_model(cfg, tp: int = 1):
     from repro.core.cost_model import (LinearCostModel, kv_bytes_per_token,
                                       per_shard_model)
 
-    b = 2.0 * cfg.active_param_count() / PEAK_FLOPS
+    b = 2.0 * cfg.active_param_count() / V5E["bf16_flops"]
     c = kv_bytes_per_token(cfg.n_layers, cfg.n_kv_heads, cfg.head_dim,
-                           "fp32") / HBM_BW
+                           "fp32") / V5E["hbm_bytes_per_s"]
     return per_shard_model(
         LinearCostModel(a=DISPATCH_OVERHEAD_S, b=b, c=c), tp)
 

@@ -1,7 +1,9 @@
 """Quickstart: serve a small model with batched requests, end to end, REAL
-execution (paged KV cache + FairBatching scheduler) on CPU.
+execution (paged KV cache + FairBatching scheduler), on the CPU or a TPU.
 
     PYTHONPATH=src python examples/quickstart.py
+
+At a model's published width on the chip, run ``chip_smoke.py`` instead.
 """
 import dataclasses
 import time
@@ -14,6 +16,7 @@ from repro.core import LinearCostModel, make_scheduler
 from repro.engine import (Engine, EngineConfig, PagedTransformerExecutor,
                           Request)
 from repro.engine.metrics import summarize
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import ModelOpts, build_model
 
 # A ~4M-param llama-style model (real weights, random init).
@@ -22,7 +25,10 @@ CFG = ArchConfig(name="demo-4m", family="dense", n_layers=4, d_model=256,
 
 
 def main() -> None:
-    print(f"model: {CFG.name} ({CFG.param_count()/1e6:.1f}M params)")
+    setup_compile_cache()
+    dev = jax.devices()[0]
+    print(f"model: {CFG.name} ({CFG.param_count()/1e6:.1f}M params) on "
+          f"{dev.platform} {dev.device_kind} x{len(jax.devices())}")
     model = build_model(CFG, ModelOpts(attn_impl="dense"))
     params = model.init(jax.random.PRNGKey(0))
     executor = PagedTransformerExecutor(CFG, params, num_pages=128,

@@ -30,8 +30,11 @@ import dataclasses
 import math
 from typing import Optional
 
+import numpy as np
+
 from ..cache.radix import split_blocks
 from ..core.cost_model import LinearCostModel, LinkModel
+from ..engine.executor import copy_pages, gather_pages, set_pages
 
 
 @dataclasses.dataclass
@@ -39,7 +42,7 @@ class KVPayload:
     """One request's paged KV, captured in table order from the source.
 
     Arrays are host-side (numpy): ``k``/``v`` are
-    (n_layers, n_pages, page_size, n_kv_heads, head_dim) in the executor's
+    (n_layers, n_pages, n_kv_heads, head_dim, page_size) in the executor's
     *storage* dtype (int8 values stay int8); ``k_scales``/``v_scales`` are
     the paired f32 dequantization scales (None for fp32 executors).
     """
@@ -95,7 +98,6 @@ def capture_kv(executor, req_id: int) -> Optional[KVPayload]:
     alloc = getattr(ex, "alloc", None)
     if alloc is None or not hasattr(ex, "k_pages"):
         return None
-    import numpy as np
     tbl = alloc.tables.get(req_id)
     if not tbl:
         return None
@@ -103,11 +105,11 @@ def capture_kv(executor, req_id: int) -> Optional[KVPayload]:
     idx = np.asarray(tbl, dtype=np.int32)
     payload = KVPayload(
         n_tokens=n, block_size=alloc.block_size,
-        k=np.asarray(ex.k_pages[:, idx]), v=np.asarray(ex.v_pages[:, idx]))
+        k=gather_pages(ex.k_pages, idx), v=gather_pages(ex.v_pages, idx))
     if getattr(ex, "k_scales", None) is not None:
         stbl = np.asarray(alloc.scale_table(req_id), dtype=np.int32)
-        payload.k_scales = np.asarray(ex.k_scales[:, stbl])
-        payload.v_scales = np.asarray(ex.v_scales[:, stbl])
+        payload.k_scales = gather_pages(ex.k_scales, stbl)
+        payload.v_scales = gather_pages(ex.v_scales, stbl)
     return payload
 
 
@@ -139,11 +141,14 @@ def _mirror_cow(ex, alloc) -> None:
     old, new, s_old, s_new = alloc.pop_cow_events_batched()
     if not old:
         return
-    ex.k_pages = ex.k_pages.at[:, new].set(ex.k_pages[:, old])
-    ex.v_pages = ex.v_pages.at[:, new].set(ex.v_pages[:, old])
+    old, new = np.asarray(old, np.int32), np.asarray(new, np.int32)
+    ex.k_pages = copy_pages(ex.k_pages, old, new)
+    ex.v_pages = copy_pages(ex.v_pages, old, new)
     if getattr(ex, "k_scales", None) is not None:
-        ex.k_scales = ex.k_scales.at[:, s_new].set(ex.k_scales[:, s_old])
-        ex.v_scales = ex.v_scales.at[:, s_new].set(ex.v_scales[:, s_old])
+        s_old = np.asarray(s_old, np.int32)
+        s_new = np.asarray(s_new, np.int32)
+        ex.k_scales = copy_pages(ex.k_scales, s_old, s_new)
+        ex.v_scales = copy_pages(ex.v_scales, s_old, s_new)
 
 
 def install_kv_pages(executor, prefix_cache, req, payload: KVPayload,
@@ -160,7 +165,7 @@ def install_kv_pages(executor, prefix_cache, req, payload: KVPayload,
     alloc = ex.alloc
     assert alloc.block_size == payload.block_size, \
         "cross-page-size migration unsupported"
-    if payload.k.dtype != ex.k_pages.dtype:
+    if payload.k.dtype != ex.k_pages[0].dtype:
         return None                      # cross-dtype pools: recompute
     max_pages = getattr(ex, "max_pages", None)
     if max_pages is not None and payload.n_pages > max_pages:
@@ -183,18 +188,17 @@ def install_kv_pages(executor, prefix_cache, req, payload: KVPayload,
     tbl = alloc.tables[req.req_id]
     nref = len(ref)
     if len(tbl) > nref:
-        import jax.numpy as jnp
-        dst = jnp.asarray(tbl[nref:])
+        dst = np.asarray(tbl[nref:], np.int32)
         sel = slice(nref, len(tbl))
-        ex.k_pages = ex.k_pages.at[:, dst].set(jnp.asarray(payload.k[:, sel]))
-        ex.v_pages = ex.v_pages.at[:, dst].set(jnp.asarray(payload.v[:, sel]))
+        ex.k_pages = set_pages(ex.k_pages, dst, payload.k[:, sel])
+        ex.v_pages = set_pages(ex.v_pages, dst, payload.v[:, sel])
         if payload.k_scales is not None \
                 and getattr(ex, "k_scales", None) is not None:
-            sdst = jnp.asarray(alloc.scale_table(req.req_id)[nref:])
-            ex.k_scales = ex.k_scales.at[:, sdst].set(
-                jnp.asarray(payload.k_scales[:, sel]))
-            ex.v_scales = ex.v_scales.at[:, sdst].set(
-                jnp.asarray(payload.v_scales[:, sel]))
+            sdst = np.asarray(alloc.scale_table(req.req_id)[nref:], np.int32)
+            ex.k_scales = set_pages(ex.k_scales, sdst,
+                                    payload.k_scales[:, sel])
+            ex.v_scales = set_pages(ex.v_scales, sdst,
+                                    payload.v_scales[:, sel])
     if prefix_cache is not None and prefix_cache.enabled and req.tokens:
         prefix_cache.insert_request(req.req_id, req.tokens, now)
     return nref

@@ -19,13 +19,16 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import math
 import time
+import weakref
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from ..configs.base import ArchConfig
 from ..core.cost_model import LinearCostModel
@@ -125,6 +128,40 @@ def _ladder(n: int, lo: int) -> int:
     return b
 
 
+def scatter_rows(k_pools: tuple, v_pools: tuple, layer: int, k, v, pages,
+                 slots) -> tuple:
+    """Write token rows k, v (N, Hkv[, D]) at (pages, slots) of layer
+    ``layer``'s pools — (P, Hkv, D, page) K/V pages or (P, Hkv, page) scale
+    pages. Returns the updated (k_pools, v_pools)."""
+    def put(pools, rows):
+        idx = (pages, slice(None)) + (slice(None),) * (rows.ndim - 2) \
+            + (slots,)
+        new = pools[layer].at[idx].set(rows)
+        return pools[:layer] + (new,) + pools[layer + 1:]
+    return put(k_pools, k), put(v_pools, v)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def copy_pages(pools: tuple, src, dst) -> tuple:
+    """Copy pages ``src`` onto pages ``dst`` in every layer's pool, in place
+    (the pools are donated; callers keep the returned tuple)."""
+    return tuple(p.at[dst].set(p[src]) for p in pools)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def set_pages(pools: tuple, dst, values) -> tuple:
+    """Write ``values`` (layers, len(dst), ...) into pages ``dst`` of every
+    layer's pool, in place."""
+    return tuple(p.at[dst].set(values[l]) for l, p in enumerate(pools))
+
+
+def gather_pages(pools: tuple, idx) -> np.ndarray:
+    """Pages ``idx`` of every layer's pool as one host array (layers,
+    len(idx), ...)."""
+    return np.stack([np.asarray(p[jnp.asarray(idx, jnp.int32)])
+                     for p in pools])
+
+
 @dataclasses.dataclass
 class _PackedSeq:
     """Host-side view of one sequence in the packed step (DESIGN.md §11)."""
@@ -214,37 +251,46 @@ class PagedTransformerExecutor:
         reserved = self.alloc.extend(-1, page_size)
         assert reserved == [0]
         self.max_pages = max_pages_per_seq
-        shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads,
-                 cfg.head_dim)
+        # one pool per layer, (page, kv head, head dim, slot): one page of
+        # one kv head is the (head_dim, page) tile the attention kernel
+        # streams (DESIGN.md §3). Separate per-layer arrays let each step
+        # update a layer's pool in place; slices of one stacked pool made
+        # XLA copy the whole pool around every kernel launch.
+        shape = (num_pages, cfg.n_kv_heads, cfg.head_dim, page_size)
         kv_store = jnp.float32 if self.qspec is None else self.qspec.dtype
         # page pools shard on the kv-head dim over `model` (a no-op when the
         # rules table replicated kv_heads for indivisible head counts);
         # page/slot dims stay replicated so the host-global page IDs of the
         # allocator index every shard identically (DESIGN.md §17)
         self._kv_sharding = (None if mesh is None else self.rules.sharding(
-            (None, None, None, "kv_heads", None)))
+            (None, "kv_heads", None, None)))
         self._scale_sharding = (None if mesh is None else self.rules.sharding(
-            (None, None, None, "kv_heads")))
-        self.k_pages = self._shard_kv(jnp.zeros(shape, kv_store))
-        self.v_pages = self._shard_kv(jnp.zeros(shape, kv_store))
+            (None, "kv_heads", None)))
+        # mesh axis the attention kernels split kv heads (and their query
+        # heads) over; None when unsharded or when the heads do not divide
+        self._head_axis = None
+        if mesh is not None and self.rules.table["kv_heads"] is not None \
+                and self.rules.table["q_heads"] == self.rules.table["kv_heads"]:
+            self._head_axis = self.rules.table["kv_heads"]
+        def pools(shape, dtype):
+            return tuple(jnp.zeros(shape, dtype) for _ in range(cfg.n_layers))
+        self.k_pages = self._shard_kv(pools(shape, kv_store))
+        self.v_pages = self._shard_kv(pools(shape, kv_store))
         if self.qspec is None:
             self.k_scales = self.v_scales = None
         else:
-            sshape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads)
-            self.k_scales = self._shard_scale(jnp.zeros(sshape, jnp.float32))
-            self.v_scales = self._shard_scale(jnp.zeros(sshape, jnp.float32))
+            sshape = (num_pages, cfg.n_kv_heads, page_size)
+            self.k_scales = self._shard_scale(pools(sshape, jnp.float32))
+            self.v_scales = self._shard_scale(pools(sshape, jnp.float32))
             # pad tokens redirect scales to the trash page's scale page,
             # which the construction order above pins to id 0
             assert self.alloc.scale_of[0] == 0
-        self._chunk_fn = jax.jit(self._chunk_step,
-                                 static_argnames=("n_tok",))
-        self._decode_fn = jax.jit(self._decode_step,
-                                  static_argnames=("bsz",))
-        self._fused_fn = jax.jit(self._fused_step,
-                                 static_argnames=("t_bucket", "s_bucket",
-                                                  "tq_bucket", "pg_bucket"))
-        self._multi_fn = jax.jit(self._multi_decode_step,
-                                 static_argnames=("bsz", "horizon"))
+        self._chunk_fn = self._jit_step(self._chunk_step, "n_tok")
+        self._decode_fn = self._jit_step(self._decode_step, "bsz")
+        self._fused_fn = self._jit_step(self._fused_step, "t_bucket",
+                                        "s_bucket", "tq_bucket", "pg_bucket")
+        self._multi_fn = self._jit_step(self._multi_decode_step, "bsz",
+                                        "horizon")
         # speculative decode (DESIGN.md §18): a draft adapter installed via
         # set_draft() enables execute_multi(speculate=γ); force_reject
         # zeroes every acceptance in-graph (the parity edge-case switch)
@@ -280,6 +326,50 @@ class PagedTransformerExecutor:
     def _shard_scale(self, scales):
         return scales if self._scale_sharding is None else jax.device_put(
             scales, self._scale_sharding)
+
+    def _jit_step(self, fn, *static_argnames):
+        """jit a step body with the weights passed as its first argument.
+        The bodies read ``self.params``; closed over instead, the weights
+        would be embedded in every compiled program as literal constants
+        (gigabytes at published widths). The returned callable binds them,
+        so call sites pass only the step's own operands. The K/V pools and
+        scale pages (the body's first three operands) are donated: every
+        caller replaces them with the step's outputs, and without donation
+        the step would hold a second copy of the pools. The body reaches
+        the executor through a weak reference: JAX's trace caches outlive
+        the executor, and a strong one would keep its pools alive."""
+        owner, method = weakref.ref(self), fn.__func__
+
+        def body(params, *args, **kwargs):
+            ex = owner()
+            saved, ex.params = ex.params, params
+            try:
+                return method(ex, *args, **kwargs)
+            finally:
+                ex.params = saved
+        jitted = jax.jit(body, static_argnames=static_argnames,
+                         donate_argnums=(1, 2, 3))
+        step = functools.partial(jitted, self.params)
+        step._cache_size = jitted._cache_size
+        return step
+
+    def _head_parallel(self, op, q, pools, *meta, **kw):
+        """Run an attention backend on each shard's heads. ``pools`` are
+        (P, Hkv[, D], page) layer pools sharded on their kv-head dim, q has
+        its head dim second-minor, ``meta`` (tables, lengths) is replicated.
+        A Pallas kernel cannot be partitioned by GSPMD, so under a mesh
+        that splits the heads the call runs inside ``shard_map``, where
+        each device attends its own head group with no communication."""
+        if self._head_axis is None:
+            return op(q, *pools, *meta, **kw)
+        ax = self._head_axis
+        q_spec = PartitionSpec(*([None] * (q.ndim - 2)), ax, None)
+        pool_specs = tuple(PartitionSpec(None, ax, *([None] * (p.ndim - 2)))
+                           for p in pools)
+        return jax.shard_map(
+            lambda q_, *rest: op(q_, *rest, **kw), mesh=self.mesh,
+            in_specs=(q_spec,) + pool_specs + (PartitionSpec(),) * len(meta),
+            out_specs=q_spec, check_vma=False)(q, *pools, *meta)
 
     @contextlib.contextmanager
     def _step_ctx(self):
@@ -352,16 +442,11 @@ class PagedTransformerExecutor:
             if valid is not None:
                 spage_ids = jnp.where(valid, spage_ids, 0)    # → trash scales
             flat_sp = spage_ids.reshape(-1)
-            k_scales, v_scales = scales
-            k_scales = k_scales.at[layer, flat_sp, flat_sl].set(
-                ks.reshape(b * t, -1))
-            v_scales = v_scales.at[layer, flat_sp, flat_sl].set(
-                vs.reshape(b * t, -1))
-            scales = (k_scales, v_scales)
-        kf = k.reshape(b * t, *k.shape[2:])
-        vf = v.reshape(b * t, *v.shape[2:])
-        k_pages = k_pages.at[layer, flat_pg, flat_sl].set(kf)
-        v_pages = v_pages.at[layer, flat_pg, flat_sl].set(vf)
+            scales = scatter_rows(*scales, layer, ks.reshape(b * t, -1),
+                                  vs.reshape(b * t, -1), flat_sp, flat_sl)
+        k_pages, v_pages = scatter_rows(
+            k_pages, v_pages, layer, k.reshape(b * t, *k.shape[2:]),
+            v.reshape(b * t, *v.shape[2:]), flat_pg, flat_sl)
         return k_pages, v_pages, scales
 
     def _attend(self, q, k_pages, v_pages, scales, layer, table, stable,
@@ -369,12 +454,13 @@ class PagedTransformerExecutor:
         """Batched paged attention over layer ``layer``'s pages, routed to
         the fp32 or the dequantizing quantized backend."""
         if self.qspec is None:
-            return paged_attention_op(q, k_pages[layer], v_pages[layer],
-                                      table, ctx_lens, q_starts,
-                                      window=self.cfg.window)
-        return paged_attention_quant_op(
-            q, k_pages[layer], v_pages[layer], scales[0][layer],
-            scales[1][layer], table, stable, ctx_lens, q_starts,
+            return self._head_parallel(
+                paged_attention_op, q, (k_pages[layer], v_pages[layer]),
+                table, ctx_lens, q_starts, window=self.cfg.window)
+        return self._head_parallel(
+            paged_attention_quant_op, q,
+            (k_pages[layer], v_pages[layer], scales[0][layer],
+             scales[1][layer]), table, stable, ctx_lens, q_starts,
             window=self.cfg.window)
 
     def _forward(self, k_pages, v_pages, scales, x, positions, table, stable,
@@ -525,12 +611,10 @@ class PagedTransformerExecutor:
         if self.qspec is not None:
             k, ks = kvq.quantize_kv(k, self.qspec)
             v, vs = kvq.quantize_kv(v, self.qspec)
-            k_scales, v_scales = scales
-            k_scales = k_scales.at[layer, tok_spages, tok_slots].set(ks)
-            v_scales = v_scales.at[layer, tok_spages, tok_slots].set(vs)
-            scales = (k_scales, v_scales)
-        k_pages = k_pages.at[layer, tok_pages, tok_slots].set(k)
-        v_pages = v_pages.at[layer, tok_pages, tok_slots].set(v)
+            scales = scatter_rows(*scales, layer, ks, vs, tok_spages,
+                                  tok_slots)
+        k_pages, v_pages = scatter_rows(k_pages, v_pages, layer, k, v,
+                                        tok_pages, tok_slots)
         return k_pages, v_pages, scales
 
     def _fused_step(self, k_pages, v_pages, scales, tokens, positions,
@@ -572,14 +656,16 @@ class PagedTransformerExecutor:
                 tok_slots, tok_spages)
             if self._ragged_attn:
                 if self.qspec is None:
-                    o = paged_attention_ragged_op(
-                        q[0], k_pages[l], v_pages[l], tables, ctx_lens,
+                    o = self._head_parallel(
+                        paged_attention_ragged_op, q[0],
+                        (k_pages[l], v_pages[l]), tables, ctx_lens,
                         q_starts, q_lens, pos0, window=cfg.window,
                         pages_per_block=kb, q_block=tb)
                 else:
-                    o = paged_attention_ragged_quant_op(
-                        q[0], k_pages[l], v_pages[l], scales[0][l],
-                        scales[1][l], tables, stables, ctx_lens, q_starts,
+                    o = self._head_parallel(
+                        paged_attention_ragged_quant_op, q[0],
+                        (k_pages[l], v_pages[l], scales[0][l],
+                         scales[1][l]), tables, stables, ctx_lens, q_starts,
                         q_lens, pos0, window=cfg.window,
                         pages_per_block=kb, q_block=tb)
             else:
@@ -628,15 +714,14 @@ class PagedTransformerExecutor:
         their dequant scales stay in lock-step."""
         old, new, s_old, s_new = self.alloc.pop_cow_events_batched()
         if old:
-            src_k = self.k_pages[:, old]
-            src_v = self.v_pages[:, old]
-            self.k_pages = self.k_pages.at[:, new].set(src_k)
-            self.v_pages = self.v_pages.at[:, new].set(src_v)
+            old, new = jnp.asarray(old, jnp.int32), jnp.asarray(new, jnp.int32)
+            self.k_pages = copy_pages(self.k_pages, old, new)
+            self.v_pages = copy_pages(self.v_pages, old, new)
             if self.qspec is not None:
-                self.k_scales = self.k_scales.at[:, s_new].set(
-                    self.k_scales[:, s_old])
-                self.v_scales = self.v_scales.at[:, s_new].set(
-                    self.v_scales[:, s_old])
+                s_old = jnp.asarray(s_old, jnp.int32)
+                s_new = jnp.asarray(s_new, jnp.int32)
+                self.k_scales = copy_pages(self.k_scales, s_old, s_new)
+                self.v_scales = copy_pages(self.v_scales, s_old, s_new)
             if self.draft is not None:
                 # draft pools index the same global page ids (DESIGN.md §18)
                 self.draft.mirror_cow(old, new)
@@ -655,9 +740,8 @@ class PagedTransformerExecutor:
         speculative round body; enables ``execute_multi(speculate=γ)``."""
         draft.bind(self)
         self.draft = draft
-        self._spec_fn = jax.jit(
-            self._spec_multi_step,
-            static_argnames=("bsz", "rounds", "gamma", "force_reject"))
+        self._spec_fn = self._jit_step(self._spec_multi_step, "bsz",
+                                       "rounds", "gamma", "force_reject")
 
     def execute_multi(self, plan: BatchPlan, requests, now: float,
                       horizon: int, *, speculate: int = 0) -> tuple[list, dict]:

@@ -56,8 +56,9 @@ class LayerParity:
 
 
 def _gather_rows(pages, table, n_tokens):
-    """pages: (P, page, ...) for one layer; table: page ids → (n, ...)."""
-    g = pages[jnp.asarray(table, jnp.int32)]      # (n_pages, page, ...)
+    """pages: (P, Hkv[, D], page) for one layer; table: page ids
+    → (n, Hkv[, D]) token rows."""
+    g = jnp.moveaxis(pages[jnp.asarray(table, jnp.int32)], -1, 1)
     return g.reshape(-1, *g.shape[2:])[:n_tokens]
 
 

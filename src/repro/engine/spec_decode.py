@@ -43,6 +43,7 @@ from ..configs.base import ArchConfig
 from ..kernels.ops import paged_attention_op
 from ..models.layers import attn_qkv, mlp_apply
 from ..models.module import rmsnorm
+from .executor import copy_pages, scatter_rows
 
 
 class AcceptanceEWMA:
@@ -128,7 +129,8 @@ class TruncatedSelfDraft:
 
 def _draft_attend_write(dk, dv, layer, k, v, tables, positions, page_size,
                         valid=None):
-    """Scatter a draft step's K/V into the draft pools at (page, slot)."""
+    """Scatter a draft step's K/V into layer ``layer``'s draft pools (per
+    layer, (P, Hkv, D, page) like the target's) at (page, slot)."""
     b, t = positions.shape
     page_ids = jnp.take_along_axis(tables, positions // page_size, axis=1)
     slots = positions % page_size
@@ -136,9 +138,8 @@ def _draft_attend_write(dk, dv, layer, k, v, tables, positions, page_size,
         page_ids = jnp.where(valid, page_ids, 0)          # → trash page
     flat_pg = page_ids.reshape(-1)
     flat_sl = slots.reshape(-1)
-    dk = dk.at[layer, flat_pg, flat_sl].set(k.reshape(b * t, *k.shape[2:]))
-    dv = dv.at[layer, flat_pg, flat_sl].set(v.reshape(b * t, *v.shape[2:]))
-    return dk, dv
+    return scatter_rows(dk, dv, layer, k.reshape(b * t, *k.shape[2:]),
+                        v.reshape(b * t, *v.shape[2:]), flat_pg, flat_sl)
 
 
 def draft_forward(cfg: ArchConfig, params, dk, dv, x, positions, tables,
@@ -198,10 +199,12 @@ class SmallModelDraft:
         cfg = self.cfg
         self._ex = executor
         self.page_size = executor.page_size
-        shape = (cfg.n_layers, executor.alloc.num_blocks, self.page_size,
-                 cfg.n_kv_heads, cfg.head_dim)
-        self.dk = jnp.zeros(shape, jnp.float32)
-        self.dv = jnp.zeros(shape, jnp.float32)
+        shape = (executor.alloc.num_blocks, cfg.n_kv_heads, cfg.head_dim,
+                 self.page_size)
+        self.dk = tuple(jnp.zeros(shape, jnp.float32)
+                        for _ in range(cfg.n_layers))
+        self.dv = tuple(jnp.zeros(shape, jnp.float32)
+                        for _ in range(cfg.n_layers))
         self._prefill_fn = jax.jit(self._prefill_step,
                                    static_argnames=("n_tok",))
 
@@ -272,8 +275,8 @@ class SmallModelDraft:
         """Mirror the target allocator's COW page copies: draft pools share
         the global page-id space, so a copied data page's draft-KV must
         follow it or the surviving holders would read the wrong rows."""
-        self.dk = self.dk.at[:, new].set(self.dk[:, old])
-        self.dv = self.dv.at[:, new].set(self.dv[:, old])
+        self.dk = copy_pages(self.dk, old, new)
+        self.dv = copy_pages(self.dv, old, new)
 
 
 def _chunk_bucket(n: int, lo: int = 16) -> int:

@@ -7,28 +7,39 @@ One kernel serves both FairBatching step item types:
                     into the page pool by the executor)
 
 Layout/TPU adaptation (DESIGN.md §3): the KV cache lives in HBM as 128-token
-pages; the block table rides scalar-prefetch so each grid step's BlockSpec
-index_map resolves its page id and the DMA pipeline streams page tiles
-HBM→VMEM. Online softmax accumulates in f32 VMEM scratch across the page
-axis of the grid (sequential on TPU), GQA query heads of one KV head are
-packed into the sublane dim so the MXU sees (Tq·G, D) × (D, page) tiles.
+pages, one pool per layer laid out ``(P, Hkv, D, page)``: one page of one kv
+head is a ``(D, page)`` tile with the page's tokens on the lanes. The TPU
+compiler requires a block's two minor dims to be 8- and 128-aligned or
+whole, and the pool's own tiled layout pads neither (D = 80 would pad to
+128 lanes in a ``(page, D)`` tile, and XLA would copy the pool into that
+layout around every launch). The block table rides scalar-prefetch so each
+grid step's BlockSpec index_map resolves its page id and the DMA pipeline
+streams page tiles HBM→VMEM. Online softmax accumulates in f32 VMEM scratch
+across the page axis of the grid (sequential on TPU); the GQA query heads
+of one KV head are packed token-major into the sublane dim outside the
+kernel, so the MXU sees (Tq·G, D) × (D, page) tiles with no in-kernel
+reshape.
 
-Block-shape tuning (DESIGN.md §14): the ragged kernel's grid is tiled by
+Block-shape tuning (DESIGN.md §14): the kernel's grid is tiled by
 ``(pages_per_block, q_block)`` — how many KV pages stream through VMEM per
-grid step, and how many packed query rows each output tile covers. The
+grid step, and how many packed query tokens each output tile covers. The
 analytic autotuner (benchmarks/autotune_attention.py, roofline + HLO byte
 model) sweeps the candidates per (token-bucket, pages-bucket) and records
 winners in the module registry below; the executor consults it per compile
-key. Defaults reproduce the untiled PR 3 kernel exactly.
+key. Untuned buckets take one page per grid step and ``DEFAULT_Q_TILE``.
 
 Quantized variant (DESIGN.md §14): ``paged_attention_ragged_quant`` reads
-int8/fp8 value pages plus per-(token, kv-head) f32 scale pages and
-dequantizes inside the kernel, after the DMA and before the MXU — HBM
-traffic is the quantized byte count.
+int8/fp8 value pages plus per-(token, kv-head) f32 scale pages laid out
+``(Ps, Hkv, page)`` and dequantizes inside the kernel, after the DMA and
+before the softmax — HBM traffic is the quantized byte count.
+
+The batched ``paged_attention`` (B sequences × Tq rows) is the same kernel
+over a stream in which every sequence owns Tq rows.
 
 Oracle: ref.paged_attention_ref / ref.paged_attention_ragged_quant_ref.
 Validated with interpret=True over shape/dtype sweeps in
-tests/test_kernels.py.
+tests/test_kernels.py, and compiled for a described TPU v5e at published
+widths in tests/test_chip_compile.py.
 """
 from __future__ import annotations
 
@@ -42,9 +53,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# query tokens per tile when the caller gives no q_block (see
+# _resolve_tiling); a multiple of 8 rows for any GQA group size
+DEFAULT_Q_TILE = 128
+
 # autotuned (pages_per_block, q_block) per (mesh_key, t_bucket,
 # pages_bucket) — filled by benchmarks/autotune_attention.py
-# (set_ragged_tilings); (1, None) = the untiled PR 3 grid. Keying by mesh
+# (set_ragged_tilings); (1, None) = the default grid. Keying by mesh
 # shape matters under TP (DESIGN.md §17): a sharded kernel sees
 # n_kv_heads/TP head groups and per-shard VMEM working sets, so a winner
 # tuned single-device is NOT a winner for the sharded launch — lookups for
@@ -88,67 +103,44 @@ def get_ragged_tiling(t_bucket: int, pages_bucket: int,
                               (1, None))
 
 
-def _kernel(block_table, context_lens, q_starts,   # scalar-prefetch refs
-            q_ref, k_ref, v_ref, o_ref,            # VMEM blocks
-            m_s, l_s, acc_s,                       # scratch
-            *, page: int, n_pages: int, tq: int, g: int, window: Optional[int],
-            scale: float):
-    b = pl.program_id(0)
-    p_idx = pl.program_id(2)
-
-    @pl.when(p_idx == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    q = q_ref[0, :, 0, :, :].astype(jnp.float32).reshape(tq * g, -1)  # (TqG, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                         # (page, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-
-    kv_pos = p_idx * page + jax.lax.broadcasted_iota(jnp.int32, (tq * g, page), 1)
-    q_pos = (q_starts[b] +
-             jax.lax.broadcasted_iota(jnp.int32, (tq * g, page), 0) // g)
-    mask = (kv_pos < context_lens[b]) & (kv_pos <= q_pos)
-    if window is not None:
-        mask &= (q_pos - kv_pos) < window
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(mask, p, 0.0)
-    l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_s[...] = m_new
-
-    @pl.when(p_idx == n_pages - 1)
-    def _flush():
-        out = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
-        o_ref[...] = out.reshape(1, tq, 1, g, -1).astype(o_ref.dtype)
+def _resolve_tiling(t: int, n_pages: int, pages_per_block: Optional[int],
+                    q_block: Optional[int]) -> tuple[int, int]:
+    """Clamp the requested (kb, tb) to the launch's shape. ``tb`` must tile
+    the stream exactly (Pallas blocks are uniform) — a non-divisor falls
+    back to the untiled ``tb = t``. ``q_block=None`` tiles a stream at
+    ``DEFAULT_Q_TILE`` tokens when that divides it: the untiled query,
+    output and softmax-scratch blocks of a multi-thousand-token prefill
+    step would not fit the chip's scoped VMEM."""
+    kb = max(1, min(int(pages_per_block or 1), n_pages))
+    if q_block is None:
+        q_block = DEFAULT_Q_TILE if t % DEFAULT_Q_TILE == 0 else t
+    tb = max(1, min(int(q_block), t))
+    if t % tb:
+        tb = t
+    return kb, tb
 
 
-def _ragged_impl(block_tables, context_lens, q_starts, q_lens, pos0, refs,
-                 *, page: int, kb: int, n_pb: int, n_seq: int, tb: int,
-                 g: int, window: Optional[int], scale: float, quant: bool):
-    """Shared tiled ragged kernel body (fp32 and quantized).
+def _ragged_kernel(block_tables, context_lens, q_starts, q_lens, pos0,
+                   *refs, page: int, kb: int, n_pb: int, n_seq: int, tb: int,
+                   g: int, window: Optional[int], scale: float, quant: bool):
+    """Tiled ragged kernel body (fp32 and quantized).
 
     Grid (kv_head, q_block, seq, page_block): each grid step streams ``kb``
     pages (as ``kb`` separate scalar-prefetch-indexed tiles of the same page
-    pool) against one ``tb``-row query tile; online-softmax scratch spans
-    the query tile and persists across the (seq, page_block) inner loops.
-    When ``quant`` the page tiles are int8/fp8 and per-(token, kv-head) f32
-    scale tiles ride along; dequantization happens here, post-DMA.
+    pool) against one ``tb``-token query tile, whose ``tb·g`` rows hold the
+    ``g`` query heads of this kv head token-major; online-softmax scratch
+    spans the query tile and persists across the (seq, page_block) inner
+    loops. When ``quant`` the page tiles are int8/fp8 and each page's
+    (Hkv, page) f32 scale tile rides along: a key's scale multiplies its
+    score column and a value's scale its probability column, post-DMA.
     """
+    hk = pl.program_id(0)
     qb_idx = pl.program_id(1)
     s_idx = pl.program_id(2)
     pb_idx = pl.program_id(3)
 
+    if quant:
+        refs = refs[1:]               # scale_tables: read by index maps only
     q_ref = refs[0]
     k_refs = refs[1:1 + kb]
     v_refs = refs[1 + kb:1 + 2 * kb]
@@ -175,16 +167,15 @@ def _ragged_impl(block_tables, context_lens, q_starts, q_lens, pos0, refs,
     @pl.when((q_lens[s_idx] > 0) & overlap
              & (pb_idx * kb * page < context_lens[s_idx]))
     def _compute():
-        q = q_ref[:, 0, :, :].astype(jnp.float32).reshape(tb * g, -1)
+        q = q_ref[0].astype(jnp.float32)                  # (tb·g, D)
         for j in range(kb):
-            k = k_refs[j][0, :, 0, :].astype(jnp.float32)     # (page, D)
-            v = v_refs[j][0, :, 0, :].astype(jnp.float32)
-            if quant:
-                k = k * ks_refs[j][0, :, 0][:, None]
-                v = v * vs_refs[j][0, :, 0][:, None]
+            k = k_refs[j][0, 0].astype(jnp.float32)       # (D, page)
+            v = v_refs[j][0, 0].astype(jnp.float32)
 
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+            s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
+            if quant:
+                s = s * ks_refs[j][0, pl.ds(hk, 1), :]    # (1, page)
 
             tok = (row0 + jax.lax.broadcasted_iota(
                 jnp.int32, (tb * g, page), 0) // g)
@@ -204,40 +195,85 @@ def _ragged_impl(block_tables, context_lens, q_starts, q_lens, pos0, refs,
             p = jnp.exp(s - m_new)
             p = jnp.where(mask, p, 0.0)
             l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            if quant:
+                p = p * vs_refs[j][0, pl.ds(hk, 1), :]
             acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
+                p, v, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_s[...] = m_new
 
     @pl.when((s_idx == n_seq - 1) & (pb_idx == n_pb - 1))
     def _flush():
         out = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
-        o_ref[...] = out.reshape(tb, 1, g, -1).astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _ragged_kernel(block_tables, context_lens, q_starts, q_lens, pos0,
-                   *refs, **kw):
-    _ragged_impl(block_tables, context_lens, q_starts, q_lens, pos0, refs,
-                 quant=False, **kw)
+def _ragged_call(q, k_pages, v_pages, scale_pages, block_tables,
+                 scale_tables, context_lens, q_starts, q_lens, pos0, *,
+                 window, scale, pages_per_block, q_block, interpret):
+    """Launch the ragged kernel; ``scale_pages`` is None for fp32 pages or
+    the (k_scales, v_scales) pair of (Ps, Hkv, page) f32 scale pools."""
+    t, h, d = q.shape
+    n_seq, n_pages = block_tables.shape
+    _, hkv, _, page = k_pages.shape
+    g = h // hkv
+    scale = scale if scale is not None else d ** -0.5
+    quant = scale_pages is not None
+    kb, tb = _resolve_tiling(t, n_pages, pages_per_block, q_block)
+    n_pb = -(-n_pages // kb)
+    n_qb = t // tb
+    if n_pb * kb != n_pages:   # pad table columns; masked past context
+        pad = ((0, 0), (0, n_pb * kb - n_pages))
+        block_tables = jnp.pad(block_tables, pad)
+        if quant:
+            scale_tables = jnp.pad(scale_tables, pad)
+    # (T, H, D) → (Hkv, T·g, D): one kv head's g query heads packed
+    # token-major, so a query tile is a plain (tb·g, D) block
+    qr = q.reshape(t, hkv, g, d).transpose(1, 0, 2, 3).reshape(hkv, t * g, d)
 
+    def _page_spec(j):
+        return pl.BlockSpec((1, 1, d, page),
+                            lambda hk, qb, s, pb, bt, *_, j=j:
+                                (bt[s, pb * kb + j], hk, 0, 0))
 
-def _ragged_quant_kernel(block_tables, context_lens, q_starts, q_lens, pos0,
-                         scale_tables, *refs, **kw):
-    # scale_tables only drives the scale-tile index maps; body never reads it
-    _ragged_impl(block_tables, context_lens, q_starts, q_lens, pos0, refs,
-                 quant=True, **kw)
+    def _scale_spec(j):
+        # the whole (Hkv, page) scale page: a (1, page) block would leave
+        # the second-minor dim neither 8-aligned nor whole
+        return pl.BlockSpec((1, hkv, page),
+                            lambda hk, qb, s, pb, bt, cl, qs, ql, p0, st,
+                            j=j: (st[s, pb * kb + j], 0, 0))
 
-
-def _resolve_tiling(t: int, n_pages: int, pages_per_block: Optional[int],
-                    q_block: Optional[int]) -> tuple[int, int]:
-    """Clamp the requested (kb, tb) to the launch's shape. ``tb`` must tile
-    the stream exactly (Pallas blocks are uniform) — a non-divisor falls
-    back to the untiled ``tb = t``."""
-    kb = max(1, min(int(pages_per_block or 1), n_pages))
-    tb = t if q_block is None else max(1, min(int(q_block), t))
-    if t % tb:
-        tb = t
-    return kb, tb
+    q_spec = pl.BlockSpec((1, tb * g, d), lambda hk, qb, *_: (hk, qb, 0))
+    in_specs = ([q_spec] + [_page_spec(j) for j in range(kb)]
+                + [_page_spec(j) for j in range(kb)])
+    operands = [qr] + [k_pages] * kb + [v_pages] * kb
+    prefetch = [block_tables, context_lens, q_starts, q_lens, pos0]
+    if quant:
+        in_specs += ([_scale_spec(j) for j in range(kb)]
+                     + [_scale_spec(j) for j in range(kb)])
+        operands += [scale_pages[0]] * kb + [scale_pages[1]] * kb
+        prefetch.append(scale_tables)
+    kernel = functools.partial(_ragged_kernel, page=page, kb=kb, n_pb=n_pb,
+                               n_seq=n_seq, tb=tb, g=g, window=window,
+                               scale=scale, quant=quant)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(hkv, n_qb, n_seq, n_pb),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((tb * g, 1), jnp.float32),
+                pltpu.VMEM((tb * g, 1), jnp.float32),
+                pltpu.VMEM((tb * g, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((hkv, t * g, d), q.dtype),
+        name="paged_attention_ragged",
+        interpret=interpret,
+    )(*prefetch, *operands)
+    return out.reshape(hkv, t, g, d).transpose(1, 0, 2, 3).reshape(t, h, d)
 
 
 def paged_attention_ragged(q, k_pages, v_pages, block_tables, context_lens,
@@ -248,62 +284,20 @@ def paged_attention_ragged(q, k_pages, v_pages, block_tables, context_lens,
                            q_block: Optional[int] = None,
                            interpret: bool = False) -> jnp.ndarray:
     """Token-packed ragged paged attention: one launch for the whole hybrid
-    step (DESIGN.md §11). q: (T, H, D) packed stream; block_tables:
-    (S, n_pages); context_lens/q_starts/q_lens/pos0: (S,). Returns (T, H, D).
+    step (DESIGN.md §11). q: (T, H, D) packed stream; pages: (P, Hkv, D,
+    page); block_tables: (S, n_pages); context_lens/q_starts/q_lens/
+    pos0: (S,). Returns (T, H, D).
 
     Grid is (kv_head, q_block, seq, page_block): the online-softmax scratch
     spans one ``q_block`` query tile and each (seq, page_block) step masks
     to the rows the sequence owns; pages beyond a sequence's context, pad
     sequences, and non-overlapping query tiles early-skip. The
-    (pages_per_block, q_block) tiling is the autotuned axis (DESIGN.md §14);
-    the defaults reproduce the untiled grid.
+    (pages_per_block, q_block) tiling is the autotuned axis (DESIGN.md §14).
     """
-    t, h, d = q.shape
-    n_seq, n_pages = block_tables.shape
-    _, page, hkv, _ = k_pages.shape
-    g = h // hkv
-    scale = scale if scale is not None else d ** -0.5
-    kb, tb = _resolve_tiling(t, n_pages, pages_per_block, q_block)
-    n_pb = -(-n_pages // kb)
-    n_qb = t // tb
-    if n_pb * kb != n_pages:   # pad table columns; masked past context
-        block_tables = jnp.pad(block_tables,
-                               ((0, 0), (0, n_pb * kb - n_pages)))
-    qr = q.reshape(t, hkv, g, d)
-
-    grid = (hkv, n_qb, n_seq, n_pb)
-    kernel = functools.partial(_ragged_kernel, page=page, kb=kb, n_pb=n_pb,
-                               n_seq=n_seq, tb=tb, g=g, window=window,
-                               scale=scale)
-
-    def _page_spec(j):
-        return pl.BlockSpec((1, page, 1, d),
-                            lambda hk, qb, s, pb, bt, cl, qs, ql, p0, j=j:
-                                (bt[s, pb * kb + j], 0, hk, 0))
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=grid,
-            in_specs=(
-                [pl.BlockSpec((tb, 1, g, d),
-                              lambda hk, qb, s, pb, *_: (qb, hk, 0, 0))]
-                + [_page_spec(j) for j in range(kb)]      # k tiles
-                + [_page_spec(j) for j in range(kb)]),    # v tiles
-            out_specs=pl.BlockSpec((tb, 1, g, d),
-                                   lambda hk, qb, s, pb, *_: (qb, hk, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((tb * g, 1), jnp.float32),
-                pltpu.VMEM((tb * g, 1), jnp.float32),
-                pltpu.VMEM((tb * g, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((t, hkv, g, d), q.dtype),
-        interpret=interpret,
-    )(block_tables, context_lens, q_starts, q_lens, pos0, qr,
-      *([k_pages] * kb), *([v_pages] * kb))
-    return out.reshape(t, h, d)
+    return _ragged_call(q, k_pages, v_pages, None, block_tables, None,
+                        context_lens, q_starts, q_lens, pos0, window=window,
+                        scale=scale, pages_per_block=pages_per_block,
+                        q_block=q_block, interpret=interpret)
 
 
 def paged_attention_ragged_quant(q, k_pages, v_pages, k_scales, v_scales,
@@ -317,106 +311,30 @@ def paged_attention_ragged_quant(q, k_pages, v_pages, k_scales, v_scales,
     """Quantized-KV ragged paged attention (DESIGN.md §14).
 
     Same contract as ``paged_attention_ragged`` plus: k_pages/v_pages hold
-    int8 (or fp8-e4m3) values, k_scales/v_scales: (Ps, page, Hkv) f32 scale
+    int8 (or fp8-e4m3) values, k_scales/v_scales: (Ps, Hkv, page) f32 scale
     pages, scale_tables: (S, n_pages) scale-page ids parallel to
     block_tables (``BlockAllocator.scale_table``). Dequantization happens
     inside the kernel after the DMA — HBM reads stay at quantized width.
     """
-    t, h, d = q.shape
-    n_seq, n_pages = block_tables.shape
-    _, page, hkv, _ = k_pages.shape
-    g = h // hkv
-    scale = scale if scale is not None else d ** -0.5
-    kb, tb = _resolve_tiling(t, n_pages, pages_per_block, q_block)
-    n_pb = -(-n_pages // kb)
-    n_qb = t // tb
-    if n_pb * kb != n_pages:
-        pad = ((0, 0), (0, n_pb * kb - n_pages))
-        block_tables = jnp.pad(block_tables, pad)
-        scale_tables = jnp.pad(scale_tables, pad)
-    qr = q.reshape(t, hkv, g, d)
-
-    grid = (hkv, n_qb, n_seq, n_pb)
-    kernel = functools.partial(_ragged_quant_kernel, page=page, kb=kb,
-                               n_pb=n_pb, n_seq=n_seq, tb=tb, g=g,
-                               window=window, scale=scale)
-
-    def _page_spec(j):
-        return pl.BlockSpec((1, page, 1, d),
-                            lambda hk, qb, s, pb, bt, cl, qs, ql, p0, st, j=j:
-                                (bt[s, pb * kb + j], 0, hk, 0))
-
-    def _scale_spec(j):
-        return pl.BlockSpec((1, page, 1),
-                            lambda hk, qb, s, pb, bt, cl, qs, ql, p0, st, j=j:
-                                (st[s, pb * kb + j], 0, hk))
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
-            grid=grid,
-            in_specs=(
-                [pl.BlockSpec((tb, 1, g, d),
-                              lambda hk, qb, s, pb, *_: (qb, hk, 0, 0))]
-                + [_page_spec(j) for j in range(kb)]      # k value tiles
-                + [_page_spec(j) for j in range(kb)]      # v value tiles
-                + [_scale_spec(j) for j in range(kb)]     # k scale tiles
-                + [_scale_spec(j) for j in range(kb)]),   # v scale tiles
-            out_specs=pl.BlockSpec((tb, 1, g, d),
-                                   lambda hk, qb, s, pb, *_: (qb, hk, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((tb * g, 1), jnp.float32),
-                pltpu.VMEM((tb * g, 1), jnp.float32),
-                pltpu.VMEM((tb * g, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((t, hkv, g, d), q.dtype),
-        interpret=interpret,
-    )(block_tables, context_lens, q_starts, q_lens, pos0, scale_tables, qr,
-      *([k_pages] * kb), *([v_pages] * kb),
-      *([k_scales] * kb), *([v_scales] * kb))
-    return out.reshape(t, h, d)
+    return _ragged_call(q, k_pages, v_pages, (k_scales, v_scales),
+                        block_tables, scale_tables, context_lens, q_starts,
+                        q_lens, pos0, window=window, scale=scale,
+                        pages_per_block=pages_per_block, q_block=q_block,
+                        interpret=interpret)
 
 
 def paged_attention(q, k_pages, v_pages, block_table, context_lens, q_starts,
                     *, window: Optional[int] = None,
                     scale: Optional[float] = None,
                     interpret: bool = False) -> jnp.ndarray:
-    """q: (B, Tq, H, D); pages: (P, page, Hkv, D); block_table: (B, n_pages);
-    context_lens, q_starts: (B,). Returns (B, Tq, H, D)."""
-    bsz, tq, h, d = q.shape
-    n_pages = block_table.shape[1]
-    _, page, hkv, _ = k_pages.shape
-    g = h // hkv
-    scale = scale if scale is not None else d ** -0.5
-    qr = q.reshape(bsz, tq, hkv, g, d)
-
-    grid = (bsz, hkv, n_pages)
-    kernel = functools.partial(_kernel, page=page, n_pages=n_pages, tq=tq,
-                               g=g, window=window, scale=scale)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, tq, 1, g, d),
-                             lambda b, hk, p, *_: (b, 0, hk, 0, 0)),
-                pl.BlockSpec((1, page, 1, d),
-                             lambda b, hk, p, bt, cl, qs: (bt[b, p], 0, hk, 0)),
-                pl.BlockSpec((1, page, 1, d),
-                             lambda b, hk, p, bt, cl, qs: (bt[b, p], 0, hk, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, tq, 1, g, d),
-                                   lambda b, hk, p, *_: (b, 0, hk, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((tq * g, 1), jnp.float32),
-                pltpu.VMEM((tq * g, 1), jnp.float32),
-                pltpu.VMEM((tq * g, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((bsz, tq, hkv, g, d), q.dtype),
-        interpret=interpret,
-    )(block_table, context_lens, q_starts, qr, k_pages, v_pages)
-    return out.reshape(bsz, tq, h, d)
+    """Batched paged attention (decode Tq=1 / prefill chunk Tq=chunk) as a
+    ragged launch in which each of the B sequences owns Tq consecutive rows.
+    q: (B, Tq, H, D); pages: (P, Hkv, D, page); block_table: (B, n_pages);
+    context_lens, q_starts: (B,), q_starts the global position of q[:, 0].
+    Returns (B, Tq, H, D)."""
+    b, tq, h, d = q.shape
+    out = paged_attention_ragged(
+        q.reshape(b * tq, h, d), k_pages, v_pages, block_table, context_lens,
+        jnp.arange(b, dtype=jnp.int32) * tq, jnp.full((b,), tq, jnp.int32),
+        q_starts, window=window, scale=scale, interpret=interpret)
+    return out.reshape(b, tq, h, d)

@@ -1,7 +1,7 @@
 """Quantized KV-cache number formats (DESIGN.md §14).
 
-The paged KV cache stores values in int8 (or fp8-e4m3 where the backend
-supports the dtype) with one f32 dequantization scale per (token, kv-head)
+The paged KV cache stores values in int8 or fp8-e4m3 with one f32
+dequantization scale per (token, kv-head)
 row: ``scale = absmax(row) / qmax`` and ``value ≈ stored * scale``. Scales
 live in dedicated scale pages managed by ``BlockAllocator`` (one scale page
 per data page — see engine/kv_manager.py), so COW/fork/evict semantics are
@@ -36,24 +36,8 @@ class QuantSpec:
 
 
 _INT8 = QuantSpec("int8", jnp.int8, 127.0, 0.5 / 127.0, 1)
-
-
-def _fp8_spec() -> Optional[QuantSpec]:
-    dt = getattr(jnp, "float8_e4m3fn", None)
-    if dt is None:
-        return None
-    try:  # probe the backend: a cast round-trip must survive compilation
-        x = jnp.asarray([0.5, -1.25], jnp.float32).astype(dt)
-        if not bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))):
-            return None
-    except Exception:
-        return None
-    # e4m3fn max finite = 448; 3 mantissa bits → half-ulp relative 2^-4
-    return QuantSpec("fp8_e4m3", dt, 448.0, 2.0 ** -4, 1)
-
-
-def supports_fp8() -> bool:
-    return _fp8_spec() is not None
+# e4m3fn max finite = 448; 3 mantissa bits → half-ulp relative 2^-4
+_FP8 = QuantSpec("fp8_e4m3", jnp.float8_e4m3fn, 448.0, 2.0 ** -4, 1)
 
 
 def kv_quant_spec(kv_dtype: str) -> Optional[QuantSpec]:
@@ -63,11 +47,7 @@ def kv_quant_spec(kv_dtype: str) -> Optional[QuantSpec]:
     if kv_dtype == "int8":
         return _INT8
     if kv_dtype == "fp8_e4m3":
-        spec = _fp8_spec()
-        if spec is None:
-            raise ValueError("fp8_e4m3 KV requested but the backend has no "
-                             "float8_e4m3fn support — use int8 or fp32")
-        return spec
+        return _FP8
     raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
 
 

@@ -1,8 +1,10 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These are the ground truth the kernels' interpret-mode runs are swept
-against (tests/test_kernels.py), and the fallback implementations the engine
-uses on non-TPU backends.
+against (tests/test_kernels.py), and what the CPU test runs compute in the
+kernels' place (kernels/ops.py). A layer's page pool is laid out
+(P, Hkv, D, page) and its scale pool (P, Hkv, page), as the kernels read
+them.
 """
 from __future__ import annotations
 
@@ -15,18 +17,18 @@ NEG_INF = -1e30
 
 
 def paged_gather(pages: jnp.ndarray, block_table: jnp.ndarray) -> jnp.ndarray:
-    """pages: (P, page, Hkv, D); block_table: (B, n_pages) → (B, n_pages*page, Hkv, D)."""
-    g = pages[block_table]                  # (B, n_pages, page, Hkv, D)
+    """pages: (P, Hkv, D, page); block_table: (B, n_pages) → (B, n_pages*page, Hkv, D)."""
+    g = jnp.moveaxis(pages[block_table], -1, 2)  # (B, n_pages, page, Hkv, D)
     b, n, p, h, d = g.shape
     return g.reshape(b, n * p, h, d)
 
 
 def paged_gather_scales(scale_pages: jnp.ndarray,
                         scale_table: jnp.ndarray) -> jnp.ndarray:
-    """scale_pages: (P, page, Hkv); scale_table: (B, n_pages)
+    """scale_pages: (P, Hkv, page); scale_table: (B, n_pages)
     → (B, n_pages*page, Hkv). The scale-row companion of ``paged_gather``
     (DESIGN.md §14)."""
-    g = scale_pages[scale_table]            # (B, n_pages, page, Hkv)
+    g = jnp.moveaxis(scale_pages[scale_table], -1, 2)  # (B, n_pages, page, Hkv)
     b, n, p, h = g.shape
     return g.reshape(b, n * p, h)
 
@@ -64,7 +66,7 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, context_lens,
     """Reference ragged paged attention (decode AND chunked prefill).
 
     q: (B, Tq, H, D)       — Tq = 1 for decode, = chunk for prefill chunks
-    k_pages/v_pages: (P, page, Hkv, D)
+    k_pages/v_pages: (P, Hkv, D, page)
     block_table: (B, n_pages) int32 — page ids per sequence
     context_lens: (B,) int32 — total tokens in cache (incl. current chunk)
     q_starts: (B,) int32 — global position of q[:, 0]
@@ -84,8 +86,8 @@ def paged_attention_quant_ref(q, k_pages, v_pages, k_scales, v_scales,
     """Quantized-KV oracle (DESIGN.md §14): dequantize the gathered context
     with per-(token, kv-head) scales, then run the exact fp32 reference math.
 
-    k_pages/v_pages: (P, page, Hkv, D) int8/fp8; k_scales/v_scales:
-    (Ps, page, Hkv) f32 scale pages; scale_table: (B, n_pages) parallel to
+    k_pages/v_pages: (P, Hkv, D, page) int8/fp8; k_scales/v_scales:
+    (Ps, Hkv, page) f32 scale pages; scale_table: (B, n_pages) parallel to
     block_table (``BlockAllocator.scale_table``).
     """
     d = q.shape[-1]
@@ -109,7 +111,7 @@ def paged_attention_ragged_ref(q, k_pages, v_pages, block_tables,
 
     q: (T, H, D)           — packed stream; seq s owns rows
                              [q_starts[s], q_starts[s] + q_lens[s])
-    k_pages/v_pages: (P, page, Hkv, D)
+    k_pages/v_pages: (P, Hkv, D, page)
     block_tables: (S, n_pages) int32 — page ids per sequence
     context_lens: (S,) int32 — tokens in cache incl. this step's (0 = pad seq)
     q_starts: (S,) int32 — packed-stream offset of each sequence

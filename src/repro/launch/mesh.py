@@ -7,13 +7,22 @@ tests/conftest.py set XLA_FLAGS before importing anything).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with GSPMD ``Auto`` axes: the step bodies place
+    arrays through ``with_sharding_constraint`` under ``with mesh`` and
+    let the partitioner propagate the rest, which ``Explicit`` axes (the
+    default of ``jax.make_mesh``) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips/pod; multi-pod = 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0):
@@ -38,5 +47,5 @@ def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0):
             "tests/conftest.py; scripts set it at module top like "
             "launch/dryrun.py).")
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -25,6 +25,7 @@ from repro.data.traces import make_scenario  # noqa: E402
 from repro.disagg.migration import (capture_kv,  # noqa: E402
                                     install_kv_pages)
 from repro.engine import PagedTransformerExecutor, Request  # noqa: E402
+from repro.engine.executor import gather_pages  # noqa: E402
 from repro.engine.kv_manager import BlockAllocator  # noqa: E402
 from repro.engine.request import RequestState  # noqa: E402
 
@@ -169,18 +170,18 @@ def _migrating_run(cfg, params, kv_dtype, requests, migrate_at,
             # materialized pages are a bitwise scatter of the payload —
             # values AND (for quantized KV) the verbatim scale rows
             if len(tbl) > nref:
-                sel = jnp.asarray(tbl[nref:])
+                sel = tbl[nref:]
                 np.testing.assert_array_equal(
-                    np.asarray(dst.k_pages[:, sel]), payload.k[:, nref:])
+                    gather_pages(dst.k_pages, sel), payload.k[:, nref:])
                 np.testing.assert_array_equal(
-                    np.asarray(dst.v_pages[:, sel]), payload.v[:, nref:])
+                    gather_pages(dst.v_pages, sel), payload.v[:, nref:])
                 if payload.k_scales is not None:
-                    ssel = jnp.asarray(dst.alloc.scale_table(rid)[nref:])
+                    ssel = dst.alloc.scale_table(rid)[nref:]
                     np.testing.assert_array_equal(
-                        np.asarray(dst.k_scales[:, ssel]),
+                        gather_pages(dst.k_scales, ssel),
                         payload.k_scales[:, nref:])
                     np.testing.assert_array_equal(
-                        np.asarray(dst.v_scales[:, ssel]),
+                        gather_pages(dst.v_scales, ssel),
                         payload.v_scales[:, nref:])
         steps += 1
     streams = {rid: list(r.generated_tokens) for rid, r in world.items()}
@@ -339,19 +340,20 @@ def test_install_rejects_unhostable_table_and_rolls_back(setup):
 
 
 class _TinyExec:
-    """Minimal data plane: BlockAllocator + jnp page arrays (+ scale pools
-    in quantized trim) — enough surface for capture/install to run."""
+    """Minimal data plane: BlockAllocator + one-layer jnp page pools (+
+    scale pools in quantized trim) — enough surface for capture/install to
+    run."""
 
     def __init__(self, num_pages, quant, block=4):
         self.alloc = BlockAllocator(num_pages, block)
-        shape = (1, num_pages, block, 1, 2)
+        shape = (num_pages, 1, 2, block)
         dt = jnp.int8 if quant else jnp.float32
-        self.k_pages = jnp.zeros(shape, dt)
-        self.v_pages = jnp.zeros(shape, dt)
+        self.k_pages = (jnp.zeros(shape, dt),)
+        self.v_pages = (jnp.zeros(shape, dt),)
         if quant:
-            sshape = (1, num_pages, block, 1)
-            self.k_scales = jnp.zeros(sshape, jnp.float32)
-            self.v_scales = jnp.zeros(sshape, jnp.float32)
+            sshape = (num_pages, 1, block)
+            self.k_scales = (jnp.zeros(sshape, jnp.float32),)
+            self.v_scales = (jnp.zeros(sshape, jnp.float32),)
         self.max_pages = num_pages
         self.block = block
 
@@ -359,12 +361,12 @@ class _TinyExec:
         if self.alloc.extend(rid, n) is None:
             return False
         idx = jnp.asarray(self.alloc.tables[rid])
-        self.k_pages = self.k_pages.at[:, idx].set(fill)
-        self.v_pages = self.v_pages.at[:, idx].set(-fill)
+        self.k_pages = (self.k_pages[0].at[idx].set(fill),)
+        self.v_pages = (self.v_pages[0].at[idx].set(-fill),)
         if hasattr(self, "k_scales"):
             stbl = jnp.asarray(self.alloc.scale_table(rid))
-            self.k_scales = self.k_scales.at[:, stbl].set(float(fill))
-            self.v_scales = self.v_scales.at[:, stbl].set(float(fill) + 0.5)
+            self.k_scales = (self.k_scales[0].at[stbl].set(float(fill)),)
+            self.v_scales = (self.v_scales[0].at[stbl].set(float(fill) + 0.5),)
         return True
 
     def release(self, rid):
@@ -404,9 +406,8 @@ def _run_migration_program(program, num_pages, quant):
                 tbl = dst.alloc.tables[rid]
                 assert dst.alloc.lens[rid] == payload.n_tokens
                 if len(tbl) > nref:          # materialized tail is bitwise
-                    sel = jnp.asarray(tbl[nref:])
                     np.testing.assert_array_equal(
-                        np.asarray(dst.k_pages[:, sel]),
+                        gather_pages(dst.k_pages, tbl[nref:]),
                         payload.k[:, nref:])
         elif op == "release_dst" and rid in dst.alloc.tables:
             cache.end_request(rid)

@@ -37,8 +37,8 @@ def test_paged_attention_sweep(B, Tq, H, Hkv, D, page, n_pages, window, dtype):
     P = n_pages * 2 + 1
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, Tq, H, D)).astype(dtype)
-    kp = jax.random.normal(ks[1], (P, page, Hkv, D)).astype(dtype)
-    vp = jax.random.normal(ks[2], (P, page, Hkv, D)).astype(dtype)
+    kp = jax.random.normal(ks[1], (P, Hkv, D, page)).astype(dtype)
+    vp = jax.random.normal(ks[2], (P, Hkv, D, page)).astype(dtype)
     bt = jax.random.randint(ks[3], (B, n_pages), 0, P)
     total = page * n_pages
     ctx = jnp.asarray([(total * (i + 1)) // (B + 1) + Tq for i in range(B)],
@@ -115,8 +115,8 @@ def test_paged_attention_ragged_sweep(q_lens, pos0, H, Hkv, D, page, n_pages,
     q_starts, T = _packed_layout(q_lens, gap=3)
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (T, H, D)).astype(dtype)
-    kp = jax.random.normal(ks[1], (P, page, Hkv, D)).astype(dtype)
-    vp = jax.random.normal(ks[2], (P, page, Hkv, D)).astype(dtype)
+    kp = jax.random.normal(ks[1], (P, Hkv, D, page)).astype(dtype)
+    vp = jax.random.normal(ks[2], (P, Hkv, D, page)).astype(dtype)
     bt = jax.random.randint(ks[3], (S, n_pages), 0, P)
     ctx = jnp.asarray([p + n for p, n in zip(pos0, q_lens)], jnp.int32)
     ctx = jnp.minimum(ctx, page * n_pages)
@@ -150,8 +150,8 @@ def test_paged_attention_ragged_hypothesis_layouts():
     page, n_pages, Hkv, G, D, T = 8, 4, 2, 2, 16, 32
     P = 9
     ks = jax.random.split(KEY, 3)
-    kp = jax.random.normal(ks[0], (P, page, Hkv, D))
-    vp = jax.random.normal(ks[1], (P, page, Hkv, D))
+    kp = jax.random.normal(ks[0], (P, Hkv, D, page))
+    vp = jax.random.normal(ks[1], (P, Hkv, D, page))
     q = jax.random.normal(ks[2], (T, Hkv * G, D))
 
     @st.composite
@@ -199,11 +199,8 @@ def test_paged_attention_ragged_hypothesis_layouts():
 
 
 def _quant_specs():
-    """Every KV quantization format the backend supports."""
-    specs = [kvq.kv_quant_spec("int8")]
-    if kvq.supports_fp8():
-        specs.append(kvq.kv_quant_spec("fp8_e4m3"))
-    return specs
+    """Every KV quantization format."""
+    return [kvq.kv_quant_spec("int8"), kvq.kv_quant_spec("fp8_e4m3")]
 
 
 def test_kv_quant_round_trip_bound():
@@ -223,6 +220,14 @@ def test_kv_quant_round_trip_bound():
         assert worst <= 0.0, f"{spec.name}: bound violated by {worst}"
         v0, s0 = kvq.quantize_kv(jnp.zeros((3, 8)), spec)
         assert bool(jnp.all(kvq.dequantize_kv(v0, s0) == 0.0)), spec.name
+
+
+def _quantize_pool(pages, spec):
+    """Quantize a (P, Hkv, D, page) pool per (token, kv-head) row, as the
+    executor's scatter does: values stay (P, Hkv, D, page), scales are
+    (P, Hkv, page)."""
+    vals, scales = kvq.quantize_kv(jnp.swapaxes(pages, -1, -2), spec)
+    return jnp.swapaxes(vals, -1, -2), scales
 
 
 def _quant_attention_tol(q, kp, vp, spec, *, scale):
@@ -266,8 +271,8 @@ def _quant_layout(q_lens, pos0, H, Hkv, D, page, n_pages, window, seed=0):
     q_starts, T = _packed_layout(q_lens, gap=3)
     ks = jax.random.split(jax.random.fold_in(KEY, seed), 4)
     q = jax.random.normal(ks[0], (T, H, D))
-    kp = jax.random.normal(ks[1], (P, page, Hkv, D))
-    vp = jax.random.normal(ks[2], (P, page, Hkv, D))
+    kp = jax.random.normal(ks[1], (P, Hkv, D, page))
+    vp = jax.random.normal(ks[2], (P, Hkv, D, page))
     bt = jax.random.randint(ks[3], (S, n_pages), 0, P)
     ctx = jnp.minimum(jnp.asarray([p + n for p, n in zip(pos0, q_lens)],
                                   jnp.int32), page * n_pages)
@@ -297,8 +302,8 @@ def test_paged_attention_ragged_quant_sweep(q_lens, pos0, H, Hkv, D, page,
     for spec in _quant_specs():
         q, kp, vp, bt, ctx, (qs, ql, p0), q_starts, T = _quant_layout(
             q_lens, pos0, H, Hkv, D, page, n_pages, window)
-        kq, ks_ = kvq.quantize_kv(kp, spec)
-        vq, vs_ = kvq.quantize_kv(vp, spec)
+        kq, ks_ = _quantize_pool(kp, spec)
+        vq, vs_ = _quantize_pool(vp, spec)
         expect = paged_attention_ragged_ref(q, kp, vp, bt, ctx, qs, ql, p0,
                                             window=window)
         got = paged_attention_ragged_quant_ref(
@@ -330,8 +335,8 @@ def test_paged_attention_ragged_quant_tilings_slow(kb, tb):
         for spec in _quant_specs():
             q, kp, vp, bt, ctx, (qs, ql, p0), q_starts, T = _quant_layout(
                 q_lens, pos0, H, Hkv, D, page, n_pages, window, seed=seed)
-            kq, ks_ = kvq.quantize_kv(kp, spec)
-            vq, vs_ = kvq.quantize_kv(vp, spec)
+            kq, ks_ = _quantize_pool(kp, spec)
+            vq, vs_ = _quantize_pool(vp, spec)
             oracle = paged_attention_ragged_quant_ref(
                 q, kq, vq, ks_, vs_, bt, bt, ctx, qs, ql, p0, window=window)
             out = paged_attention_ragged_quant(
@@ -350,12 +355,12 @@ def test_paged_attention_ignores_garbage_beyond_context():
     B, Tq, H, Hkv, D, page = 1, 1, 2, 1, 16, 16
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, Tq, H, D))
-    kp = jax.random.normal(ks[1], (4, page, Hkv, D))
-    vp = jax.random.normal(ks[2], (4, page, Hkv, D))
+    kp = jax.random.normal(ks[1], (4, Hkv, D, page))
+    vp = jax.random.normal(ks[2], (4, Hkv, D, page))
     bt = jnp.array([[0, 1]], jnp.int32)
     ctx = jnp.array([20], jnp.int32)
     out1 = paged_attention(q, kp, vp, bt, ctx, ctx - 1, interpret=True)
-    kp2 = kp.at[1, 10:].set(1e4)   # garbage beyond token 20
-    vp2 = vp.at[1, 10:].set(1e4)
+    kp2 = kp.at[1, ..., 10:].set(1e4)   # garbage beyond token 20
+    vp2 = vp.at[1, ..., 10:].set(1e4)
     out2 = paged_attention(q, kp2, vp2, bt, ctx, ctx - 1, interpret=True)
     assert float(jnp.abs(out1 - out2).max()) < 1e-6

@@ -23,6 +23,7 @@ import dataclasses
 import pytest
 
 jax = pytest.importorskip("jax")
+jnp = jax.numpy
 
 from repro.core import LinearCostModel, make_scheduler
 from repro.core.cost_model import kv_bytes_per_token, kv_page_budget
@@ -169,15 +170,12 @@ def test_kv_parity_prefill_oracle(setup):
 
 
 def test_fp8_spec_gating_is_consistent():
-    """`kv_quant_spec("fp8_e4m3")` and `supports_fp8()` must agree: a
-    backend without float8_e4m3fn gets a clear ValueError, never a silent
-    int8 fallback."""
-    if kvq.supports_fp8():
-        spec = kvq.kv_quant_spec("fp8_e4m3")
-        assert spec is not None and spec.qmax == 448.0
-    else:
-        with pytest.raises(ValueError, match="fp8_e4m3"):
-            kvq.kv_quant_spec("fp8_e4m3")
+    """`kv_quant_spec` resolves every storage format by name — fp8 to the
+    e4m3fn dtype the installed JAX provides — and an unknown name is a
+    clear ValueError, never a silent int8 fallback."""
+    spec = kvq.kv_quant_spec("fp8_e4m3")
+    assert spec.dtype == jnp.float8_e4m3fn and spec.qmax == 448.0
+    assert kvq.kv_quant_spec("int8").dtype == jnp.int8
     with pytest.raises(ValueError):
         kvq.kv_quant_spec("int4")
     assert kvq.kv_quant_spec("fp32") is None

@@ -14,6 +14,7 @@ across TP degrees at equal per-shard budgets — data-plane parallelism must
 not leak into the control plane (§17's per-shard budget contract).
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -56,11 +57,12 @@ def _reset(execu):
     assert execu.alloc.extend(-1, PAGE) == [0]     # trash page
     # re-commit the page shardings (zeros_like alone would be enough on a
     # single device; under a mesh the pools must stay head-sharded)
-    execu.k_pages = execu._shard_kv(jnp.zeros_like(execu.k_pages))
-    execu.v_pages = execu._shard_kv(jnp.zeros_like(execu.v_pages))
+    zeros = functools.partial(jax.tree.map, jnp.zeros_like)
+    execu.k_pages = execu._shard_kv(zeros(execu.k_pages))
+    execu.v_pages = execu._shard_kv(zeros(execu.v_pages))
     if execu.qspec is not None:
-        execu.k_scales = execu._shard_scale(jnp.zeros_like(execu.k_scales))
-        execu.v_scales = execu._shard_scale(jnp.zeros_like(execu.v_scales))
+        execu.k_scales = execu._shard_scale(zeros(execu.k_scales))
+        execu.v_scales = execu._shard_scale(zeros(execu.v_scales))
     execu.last_deferred = frozenset()
     execu.n_dispatches = 0
     execu.compile_keys = set()
