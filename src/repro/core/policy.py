@@ -485,6 +485,9 @@ class PrefillFirstFormation:
 # ---------------------------------------------------------------------------
 
 
+FIT_WARMUP = 32     # observations before the online fit is trusted
+
+
 class SchedulerStack:
     """A scheduler assembled from one policy per stage (DESIGN.md §13).
 
@@ -519,12 +522,18 @@ class SchedulerStack:
         (cold-start conservatism only applies to calibrating stacks)."""
         return self._rls.n_obs if self._rls is not None else -1
 
+    @property
+    def calibrated(self) -> bool:
+        """Whether ``model`` is the online fit, adopted once it has seen
+        ``FIT_WARMUP`` observations, rather than the prior."""
+        return self._rls is not None and self._rls.n_obs >= FIT_WARMUP
+
     def observe(self, total_new_tokens: int, total_context: int,
                 measured_time: float) -> None:
         if self._rls is None or total_new_tokens <= 0:
             return
         self._rls.update(total_new_tokens, total_context, measured_time)
-        if self._rls.n_obs >= 32:          # warmup before trusting online fit
+        if self.calibrated:
             self.model = self._rls.model()
 
     def schedule(self, now: float, tasks: Sequence[SchedTask]) -> BatchPlan:

@@ -39,9 +39,14 @@ from ..core.cost_model import LinearCostModel
 from ..core.pab import PABAdmissionController, prefill_admission_budget
 from ..core.schedulers import Scheduler
 from ..core.types import BatchPlan, TaskKind
+from . import trace
 from .metrics import RequestMetrics, measure
 from .request import Request, RequestState
 from .spec_decode import AcceptanceEWMA
+
+# the engine's phase spans (engine/trace.py), one of each, reused every step
+_ADMIT, _PROJECT, _SCHEDULE, _HORIZON = map(
+    trace.span, ("admit", "project", "schedule", "horizon"))
 
 
 @dataclasses.dataclass
@@ -122,8 +127,9 @@ class InflightStep:
     """A launched-but-uncompleted dispatch (between begin and complete)."""
     plan: BatchPlan
     t_start: float
-    t_form: float                 # host time the batch was formed
     internal: tuple               # tuple[InternalStep, ...]; len == horizon
+    # the dispatch's step record (engine/trace.py); complete_step adds to it
+    record: trace.Record
     # req_ids the executor could not serve this dispatch (out of KV blocks):
     # their progress is NOT advanced, so the scheduler retries them
     deferred: frozenset = frozenset()
@@ -138,10 +144,6 @@ class InflightStep:
     @property
     def horizon(self) -> int:
         return len(self.internal)
-
-    @property
-    def exec_time(self) -> float:
-        return sum(s.dt for s in self.internal)
 
     @property
     def t_end(self) -> float:
@@ -175,15 +177,16 @@ class Engine:
         self.active: list[int] = []
         self.done: list[RequestMetrics] = []
         self.steps: list[StepRecord] = []
-        self.busy_time = 0.0
         # launched-but-uncompleted dispatches, oldest first (DESIGN.md §12);
         # depth 1 makes this the old single InflightStep slot
         self.inflight_q: list[InflightStep] = []
         self._stalled_steps = 0     # consecutive fully-deferred dispatches
         # control-plane accounting (DESIGN.md §12): device dispatches,
-        # host-side form/dispatch time, speculation rollbacks
+        # modelled host form/dispatch time, host time measured by the step
+        # records (control + staging), speculation rollbacks
         self.n_dispatches = 0
         self.host_time = 0.0
+        self.host_measured_ns = 0
         self.rollbacks = 0
         # earliest arrival the *driver* knows about that has not reached
         # ``pending`` yet (the event-driven replay routes arrivals at their
@@ -259,13 +262,19 @@ class Engine:
         return bool(self.active or self.pending or self.inflight_q)
 
     def host_stats(self) -> dict:
-        """Control-plane counters for metrics / LB reports (DESIGN.md §12)."""
-        return {"dispatches": self.n_dispatches,
-                "host_overhead_s": self.host_time,
-                "engine_steps": len(self.steps),
-                "rollbacks": self.rollbacks,
-                "preemptions": self.preemptions,
-                "sheds": self.sheds}
+        """Control-plane counters for metrics / LB reports (DESIGN.md §12).
+        Over a real data plane (one with a KV allocator) they include the
+        measured host seconds; a simulated engine's stay wall-clock free, so
+        its summaries repeat run to run."""
+        out = {"dispatches": self.n_dispatches,
+               "host_overhead_s": self.host_time,
+               "engine_steps": len(self.steps),
+               "rollbacks": self.rollbacks,
+               "preemptions": self.preemptions,
+               "sheds": self.sheds}
+        if hasattr(self.executor, "alloc"):
+            out["host_measured_s"] = self.host_measured_ns / 1e9
+        return out
 
     def tenant_debt(self) -> dict:
         """Per-tenant fairness debt from the scheduler stack's admission
@@ -355,24 +364,35 @@ class Engine:
         assert len(self.inflight_q) < depth, "pipeline full"
         if now is not None:
             self.now = max(self.now, now)
-        self._admit_arrivals()
-        self._poll_brownout_sheds()
-        proj, active_proj = self._projected_requests()
-        if not active_proj:
-            return None
-        t_form = self.now
-        t_launch = t_form + self.cfg.host_overhead
+        with trace.begin(self.rank) as rec:
+            return self._form_and_launch(rec, depth)
+
+    def _form_and_launch(self, rec: trace.Record,
+                         depth: int) -> Optional[InflightStep]:
+        """``begin_step``'s work, each phase a span of ``rec``."""
+        with _ADMIT:
+            self._admit_arrivals()
+            self._poll_brownout_sheds()
+        t_launch = self.now + self.cfg.host_overhead
         if self.inflight_q:
             t_launch = max(t_launch, self.inflight_q[-1].t_end)
-        tasks = self._stamp_deferred(
-            [proj[i].to_sched_task() for i in active_proj], t_launch)
-        plan = self.sched.schedule(t_launch, tasks)
+        with _PROJECT:
+            proj, active_proj = self._projected_requests()
+            if not active_proj:
+                return None
+            tasks = self._stamp_deferred(
+                [proj[i].to_sched_task() for i in active_proj], t_launch)
+        rec.calibrated = getattr(self.sched, "calibrated", False)
+        with _SCHEDULE:
+            plan = self.sched.schedule(t_launch, tasks)
         if not plan.items:
             return None
+        rec.predicted_s = plan.predicted_time
 
-        gamma = self._spec_gamma(plan, active_proj)
-        horizon = self._plan_horizon(plan, tasks, active_proj, proj, t_launch,
-                                     gamma)
+        with _HORIZON:
+            gamma = self._spec_gamma(plan, active_proj)
+            horizon = self._plan_horizon(plan, tasks, active_proj, proj,
+                                         t_launch, gamma)
         spec_extras = None
         if gamma > 0 and hasattr(self.executor, "execute_multi"):
             internal, deferred, spec_extras = self._execute_spec(
@@ -432,7 +452,8 @@ class Engine:
                     req.first_scheduled = t_launch
         self.n_dispatches += 1
         self.host_time += self.cfg.host_overhead
-        inf = InflightStep(plan, t_launch, t_form, tuple(internal), deferred,
+        trace.log(rec)
+        inf = InflightStep(plan, t_launch, tuple(internal), rec, deferred,
                            observed, spec=spec_extras)
         self.inflight_q.append(inf)
         return inf
@@ -716,6 +737,13 @@ class Engine:
         """
         assert self.inflight_q, "no step in flight"
         inf = self.inflight_q.pop(0)
+        with trace.span("complete", inf.record):
+            rec = self._apply(inf)
+        self.host_measured_ns += inf.record.host_ns
+        return rec
+
+    def _apply(self, inf: InflightStep) -> StepRecord:
+        """``complete_step``'s work on the dispatch ``inf``."""
         plan = inf.plan
         executed = 0
         t = inf.t_start
@@ -784,7 +812,6 @@ class Engine:
                 "KV pool deadlock: every batch item was deferred for "
                 "1000 consecutive steps (pool too small for the working "
                 "set; EngineConfig.preemption=True evicts victims instead)")
-        self.busy_time += inf.exec_time
         self.now = max(self.now, inf.t_end)
         self._reconcile()
         if self.cfg.preemption and self.deferred_since:

@@ -43,7 +43,12 @@ from ..kernels.paged_attention import get_ragged_tiling, mesh_tiling_key
 from ..models.layers import attn_qkv, mlp_apply
 from ..models.moe import moe_capacity, moe_dense_exact
 from ..models.module import rmsnorm
+from . import trace
 from .kv_manager import BlockAllocator
+
+# the executor's phase spans (engine/trace.py), one of each, reused
+_EXTEND, _STAGE, _H2D, _DISPATCH, _SYNC = map(
+    trace.span, ("extend", "stage", "h2d", "dispatch", "sync"))
 
 
 @dataclasses.dataclass
@@ -646,39 +651,46 @@ class PagedTransformerExecutor:
         # apply to sharded launches; install tilings before serving, the
         # jit cache keys on bucket, not tiling
         kb, tb = get_ragged_tiling(t_bucket, pg_bucket, mesh=self._mesh_key)
+        # each phase is a named scope, so the device ops it lowers to carry
+        # the phase in their metadata (read from a profiler trace)
         for l in range(cfg.n_layers):
             lp = jax.tree.map(lambda a: a[l], self.params["layers"])
-            h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
-            q, k, v = self._constrain_qkv(*attn_qkv(lp["attn"], h, pos2d,
-                                                    cfg))
-            k_pages, v_pages, scales = self._scatter_packed(
-                k_pages, v_pages, scales, l, k[0], v[0], tok_pages,
-                tok_slots, tok_spages)
-            if self._ragged_attn:
-                if self.qspec is None:
-                    o = self._head_parallel(
-                        paged_attention_ragged_op, q[0],
-                        (k_pages[l], v_pages[l]), tables, ctx_lens,
-                        q_starts, q_lens, pos0, window=cfg.window,
-                        pages_per_block=kb, q_block=tb)
+            with jax.named_scope("qkv"):
+                h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
+                q, k, v = self._constrain_qkv(*attn_qkv(lp["attn"], h, pos2d,
+                                                        cfg))
+            with jax.named_scope("kv_scatter"):
+                k_pages, v_pages, scales = self._scatter_packed(
+                    k_pages, v_pages, scales, l, k[0], v[0], tok_pages,
+                    tok_slots, tok_spages)
+            with jax.named_scope("attention"):
+                if self._ragged_attn:
+                    if self.qspec is None:
+                        o = self._head_parallel(
+                            paged_attention_ragged_op, q[0],
+                            (k_pages[l], v_pages[l]), tables, ctx_lens,
+                            q_starts, q_lens, pos0, window=cfg.window,
+                            pages_per_block=kb, q_block=tb)
+                    else:
+                        o = self._head_parallel(
+                            paged_attention_ragged_quant_op, q[0],
+                            (k_pages[l], v_pages[l], scales[0][l],
+                             scales[1][l]), tables, stables, ctx_lens,
+                            q_starts, q_lens, pos0, window=cfg.window,
+                            pages_per_block=kb, q_block=tb)
                 else:
-                    o = self._head_parallel(
-                        paged_attention_ragged_quant_op, q[0],
-                        (k_pages[l], v_pages[l], scales[0][l],
-                         scales[1][l]), tables, stables, ctx_lens, q_starts,
-                        q_lens, pos0, window=cfg.window,
-                        pages_per_block=kb, q_block=tb)
-            else:
-                qv = q[0][seq_gather]                     # (S, Tq, H, D)
-                ov = self._attend(qv, k_pages, v_pages, scales, l, tables,
-                                  stables, ctx_lens, pos0)
-                o = ov.reshape(s_bucket * tq_bucket,
-                               *ov.shape[2:])[pack_gather]
-            x = x + o.reshape(1, t_bucket, cfg.q_dim) @ lp["attn"]["wo"]
-            x = constrain(x, (None, None, "embed"))
-            x = self._layer_ffn(lp, x)
-        h_last = x[0][last_idx]                           # (S, d)
-        return k_pages, v_pages, scales, self._head(h_last)
+                    qv = q[0][seq_gather]                 # (S, Tq, H, D)
+                    ov = self._attend(qv, k_pages, v_pages, scales, l,
+                                      tables, stables, ctx_lens, pos0)
+                    o = ov.reshape(s_bucket * tq_bucket,
+                                   *ov.shape[2:])[pack_gather]
+            with jax.named_scope("oproj_ffn"):
+                x = x + o.reshape(1, t_bucket, cfg.q_dim) @ lp["attn"]["wo"]
+                x = constrain(x, (None, None, "embed"))
+                x = self._layer_ffn(lp, x)
+        with jax.named_scope("head"):
+            h_last = x[0][last_idx]                       # (S, d)
+            return k_pages, v_pages, scales, self._head(h_last)
 
     # ------------------------------------------------------------------
 
@@ -727,9 +739,13 @@ class PagedTransformerExecutor:
                 self.draft.mirror_cow(old, new)
 
     def execute(self, plan: BatchPlan, requests, now: float) -> tuple[float, dict]:
-        if self.mode == "sequential":
-            return self._execute_sequential(plan, requests, now)
-        return self._execute_fused(plan, requests, now)
+        with trace.execute() as rec:
+            if self.mode == "sequential":
+                out = self._execute_sequential(plan, requests, now)
+            else:
+                out = self._execute_fused(plan, requests, now)
+            rec.count(plan, self.last_deferred)
+        return out
 
     # ------------------------------------------------------------------
     # slack-bounded multi-step decode commitment (DESIGN.md §12)
@@ -772,17 +788,27 @@ class PagedTransformerExecutor:
                 "speculative decode path: per-step logits never leave the "
                 "device (run with commit_horizon=1/speculate=0, or disable "
                 "capture_logits)")
-        if speculate > 0:
-            return self._execute_spec(plan, requests, now, horizon, speculate)
+        with trace.execute() as rec:
+            if speculate > 0:
+                out = self._execute_spec(plan, requests, now, horizon,
+                                         speculate)
+            else:
+                out = self._execute_horizon(plan, requests, horizon)
+            rec.count(plan, self.last_deferred)
+        return out
+
+    def _execute_horizon(self, plan: BatchPlan, requests,
+                         horizon: int) -> tuple[list, dict]:
         assert not plan.prefill_items, "multi-step commitment is decode-only"
         t0 = time.perf_counter()
         deferred: set[int] = set()
         ids = []
-        for it in plan.decode_items:
-            if self._extend(it.req_id, horizon) is None:
-                deferred.add(it.req_id)   # out of KV blocks: defer & retry
-                continue
-            ids.append(it.req_id)
+        with _EXTEND:
+            for it in plan.decode_items:
+                if self._extend(it.req_id, horizon) is None:
+                    deferred.add(it.req_id)   # out of KV blocks: defer & retry
+                    continue
+                ids.append(it.req_id)
         self.last_deferred = frozenset(deferred)
         self.last_logits = {}
         if not ids:
@@ -807,14 +833,15 @@ class PagedTransformerExecutor:
         stables += [stables[0] * 0] * pad
         self.n_dispatches += 1
         self.compile_keys.add(("multi", bsz, horizon))
-        with self._step_ctx():
+        with _DISPATCH, self._step_ctx():
             self.k_pages, self.v_pages, scales, out = self._multi_fn(
                 self.k_pages, self.v_pages, self._scales_in(),
                 jnp.asarray(toks, jnp.int32), jnp.asarray(pos, jnp.int32),
                 jnp.stack(tables), jnp.stack(stables),
                 jnp.asarray(ctx, jnp.int32), bsz=bsz, horizon=horizon)
         self._set_scales(scales)
-        toks_np = np.asarray(out)                          # (horizon, bsz)
+        with _SYNC:
+            toks_np = np.asarray(out)                      # (horizon, bsz)
         dt = time.perf_counter() - t0
         emitted_seq = {rid: [int(toks_np[h, i]) for h in range(horizon)]
                        for i, rid in enumerate(ids)}
@@ -846,13 +873,14 @@ class PagedTransformerExecutor:
         G = gamma + 1
         deferred: set[int] = set()
         ids, pre_lens = [], {}
-        for it in plan.decode_items:
-            pre = self.alloc.context_len(it.req_id)
-            if self._extend(it.req_id, rounds * G) is None:
-                deferred.add(it.req_id)   # out of KV blocks: defer & retry
-                continue
-            ids.append(it.req_id)
-            pre_lens[it.req_id] = pre
+        with _EXTEND:
+            for it in plan.decode_items:
+                pre = self.alloc.context_len(it.req_id)
+                if self._extend(it.req_id, rounds * G) is None:
+                    deferred.add(it.req_id)   # out of KV blocks: defer & retry
+                    continue
+                ids.append(it.req_id)
+                pre_lens[it.req_id] = pre
         self.last_deferred = frozenset(deferred)
         self.last_logits = {}
         self.last_spec_accepted = self.last_spec_drafted = 0
@@ -881,7 +909,7 @@ class PagedTransformerExecutor:
         stables += [stables[0] * 0] * pad
         self.n_dispatches += 1
         self.compile_keys.add(("spec", bsz, rounds, gamma))
-        with self._step_ctx():
+        with _DISPATCH, self._step_ctx():
             (self.k_pages, self.v_pages, scales, dstate, emitted, counts,
              accs) = self._spec_fn(
                 self.k_pages, self.v_pages, self._scales_in(), dstate,
@@ -892,9 +920,10 @@ class PagedTransformerExecutor:
                 force_reject=self.spec_force_reject)
         self._set_scales(scales)
         self.draft.finish(dstate)
-        em = np.asarray(emitted)                          # (bsz, R·G)
-        acc = np.asarray(accs)                            # (R, bsz)
-        cnt = np.asarray(counts)
+        with _SYNC:
+            em = np.asarray(emitted)                      # (bsz, R·G)
+            acc = np.asarray(accs)                        # (R, bsz)
+            cnt = np.asarray(counts)
         dt = time.perf_counter() - t0
         emitted_rounds: list[dict] = [{} for _ in range(rounds)]
         for i, rid in enumerate(ids):
@@ -979,98 +1008,112 @@ class PagedTransformerExecutor:
         seqs: list[_PackedSeq] = []
         deferred: set[int] = set()
         prefill_rids = set()
-        for it in plan.prefill_items:
-            req = requests[it.req_id]
-            prefill_rids.add(it.req_id)
-            if self._extend(it.req_id, it.n_tokens, mirror_cow=False) is None:
-                deferred.add(it.req_id)   # out of KV blocks: defer & retry
-                continue
-            chunk = req.tokens[req.prefilled:req.prefilled + it.n_tokens]
-            seqs.append(_PackedSeq(
-                it.req_id, chunk, pos0=req.prefilled,
-                ctx=req.prefilled + len(chunk),
-                emits=req.prefilled + it.n_tokens == req.prompt_len))
-        for it in plan.decode_items:
-            req = requests[it.req_id]
-            # a single launch computes every emission at once, so it cannot
-            # feed a same-step prefill emission back into a decode item
-            assert it.req_id not in prefill_rids, \
-                "fused step: request cannot both prefill and decode in one plan"
-            if self._extend(it.req_id, 1, mirror_cow=False) is None:
-                deferred.add(it.req_id)
-                continue
-            last = req.generated_tokens[-1] if req.generated_tokens else 0
-            # the fed-back token's position: context counts it as emitted,
-            # but its K/V enters the cache only now
-            seqs.append(_PackedSeq(it.req_id, [last], pos0=req.context - 1,
-                                   ctx=req.context, emits=True))
-        self.last_deferred = frozenset(deferred)
-        self.last_logits = {}
-        if not seqs:
-            return time.perf_counter() - t0, {}
-        self._mirror_cow_batched()
+        with _EXTEND:
+            for it in plan.prefill_items:
+                req = requests[it.req_id]
+                prefill_rids.add(it.req_id)
+                if self._extend(it.req_id, it.n_tokens,
+                                mirror_cow=False) is None:
+                    deferred.add(it.req_id)   # out of KV blocks: defer & retry
+                    continue
+                chunk = req.tokens[req.prefilled:req.prefilled + it.n_tokens]
+                seqs.append(_PackedSeq(
+                    it.req_id, chunk, pos0=req.prefilled,
+                    ctx=req.prefilled + len(chunk),
+                    emits=req.prefilled + it.n_tokens == req.prompt_len))
+            for it in plan.decode_items:
+                req = requests[it.req_id]
+                # a single launch computes every emission at once, so it
+                # cannot feed a same-step prefill emission back into a
+                # decode item
+                assert it.req_id not in prefill_rids, \
+                    "fused step: request cannot both prefill and decode in one plan"
+                if self._extend(it.req_id, 1, mirror_cow=False) is None:
+                    deferred.add(it.req_id)
+                    continue
+                last = req.generated_tokens[-1] if req.generated_tokens else 0
+                # the fed-back token's position: context counts it as
+                # emitted, but its K/V enters the cache only now
+                seqs.append(_PackedSeq(it.req_id, [last],
+                                       pos0=req.context - 1,
+                                       ctx=req.context, emits=True))
+            self.last_deferred = frozenset(deferred)
+            self.last_logits = {}
+            if not seqs:
+                return time.perf_counter() - t0, {}
+            self._mirror_cow_batched()
 
-        n_tok = sum(len(s.tokens) for s in seqs)
-        t_bucket = _ladder(n_tok, 4)
-        s_bucket = _ladder(len(seqs), 4)
-        tq_bucket = _bucket(max(len(s.tokens) for s in seqs), 1)
-        # pages bucket (DESIGN.md §14): trim staged block tables to the
-        # ladder over the step's widest table — early steps attend over a
-        # fraction of max_pages_per_seq instead of always paying for it
-        if self.trim_page_tables:
-            max_pg = max(len(self.alloc.tables[s.req_id]) for s in seqs)
-            pg_bucket = min(self.max_pages, _ladder(max_pg, 2))
-        else:
-            pg_bucket = self.max_pages
-        st = self._get_staging(t_bucket, s_bucket, tq_bucket, pg_bucket)
-        quantized = self.qspec is not None
-        off = 0
-        for i, s in enumerate(seqs):
-            n = len(s.tokens)
-            pos = np.arange(s.pos0, s.pos0 + n, dtype=np.int32)
-            tbl = np.asarray(self.alloc.tables[s.req_id], np.int32)
-            assert len(tbl) <= pg_bucket, "pages bucket exceeded"
-            st["tokens"][off:off + n] = s.tokens
-            st["positions"][off:off + n] = pos
-            st["tok_pages"][off:off + n] = tbl[pos // self.page_size]
-            st["tok_slots"][off:off + n] = pos % self.page_size
-            st["tables"][i, :len(tbl)] = tbl
-            if quantized:
-                stbl = np.asarray(self.alloc.scale_table(s.req_id), np.int32)
-                st["tok_spages"][off:off + n] = stbl[pos // self.page_size]
-                st["stables"][i, :len(stbl)] = stbl
-            st["ctx"][i] = s.ctx
-            st["q_starts"][i] = off
-            st["q_lens"][i] = n
-            st["pos0"][i] = s.pos0
-            st["last_idx"][i] = off + n - 1
-            st["seq_gather"][i, :n] = np.arange(off, off + n)
-            st["pack_gather"][off:off + n] = i * tq_bucket + np.arange(n)
-            off += n
+        with _STAGE:
+            n_tok = sum(len(s.tokens) for s in seqs)
+            t_bucket = _ladder(n_tok, 4)
+            s_bucket = _ladder(len(seqs), 4)
+            tq_bucket = _bucket(max(len(s.tokens) for s in seqs), 1)
+            # pages bucket (DESIGN.md §14): trim staged block tables to the
+            # ladder over the step's widest table — early steps attend over
+            # a fraction of max_pages_per_seq instead of always paying for it
+            if self.trim_page_tables:
+                max_pg = max(len(self.alloc.tables[s.req_id]) for s in seqs)
+                pg_bucket = min(self.max_pages, _ladder(max_pg, 2))
+            else:
+                pg_bucket = self.max_pages
+            st = self._get_staging(t_bucket, s_bucket, tq_bucket, pg_bucket)
+            quantized = self.qspec is not None
+            off = 0
+            for i, s in enumerate(seqs):
+                n = len(s.tokens)
+                pos = np.arange(s.pos0, s.pos0 + n, dtype=np.int32)
+                tbl = np.asarray(self.alloc.tables[s.req_id], np.int32)
+                assert len(tbl) <= pg_bucket, "pages bucket exceeded"
+                st["tokens"][off:off + n] = s.tokens
+                st["positions"][off:off + n] = pos
+                st["tok_pages"][off:off + n] = tbl[pos // self.page_size]
+                st["tok_slots"][off:off + n] = pos % self.page_size
+                st["tables"][i, :len(tbl)] = tbl
+                if quantized:
+                    stbl = np.asarray(self.alloc.scale_table(s.req_id),
+                                      np.int32)
+                    st["tok_spages"][off:off + n] = stbl[pos // self.page_size]
+                    st["stables"][i, :len(stbl)] = stbl
+                st["ctx"][i] = s.ctx
+                st["q_starts"][i] = off
+                st["q_lens"][i] = n
+                st["pos0"][i] = s.pos0
+                st["last_idx"][i] = off + n - 1
+                st["seq_gather"][i, :n] = np.arange(off, off + n)
+                st["pack_gather"][off:off + n] = i * tq_bucket + np.arange(n)
+                off += n
 
         self.n_dispatches += 1
-        self.compile_keys.add(("fused", t_bucket, s_bucket, tq_bucket,
-                               pg_bucket))
+        key = ("fused", t_bucket, s_bucket, tq_bucket, pg_bucket)
+        rec = trace.current()                 # ``execute``'s record
+        rec.buckets, rec.new_key = key[1:], key not in self.compile_keys
+        self.compile_keys.add(key)
         with self._step_ctx():
-            self.k_pages, self.v_pages, scales, logits = self._fused_fn(
-                self.k_pages, self.v_pages, self._scales_in(),
-                jnp.asarray(st["tokens"]), jnp.asarray(st["positions"]),
-                jnp.asarray(st["tok_pages"]), jnp.asarray(st["tok_slots"]),
-                jnp.asarray(st["tok_spages"]),
-                jnp.asarray(st["tables"]), jnp.asarray(st["stables"]),
-                jnp.asarray(st["ctx"]),
-                jnp.asarray(st["q_starts"]), jnp.asarray(st["q_lens"]),
-                jnp.asarray(st["pos0"]), jnp.asarray(st["last_idx"]),
-                jnp.asarray(st["seq_gather"]),
-                jnp.asarray(st["pack_gather"]),
-                t_bucket=t_bucket, s_bucket=s_bucket, tq_bucket=tq_bucket,
-                pg_bucket=pg_bucket)
+            with _H2D:
+                args = (jnp.asarray(st["tokens"]),
+                        jnp.asarray(st["positions"]),
+                        jnp.asarray(st["tok_pages"]),
+                        jnp.asarray(st["tok_slots"]),
+                        jnp.asarray(st["tok_spages"]),
+                        jnp.asarray(st["tables"]), jnp.asarray(st["stables"]),
+                        jnp.asarray(st["ctx"]),
+                        jnp.asarray(st["q_starts"]),
+                        jnp.asarray(st["q_lens"]),
+                        jnp.asarray(st["pos0"]), jnp.asarray(st["last_idx"]),
+                        jnp.asarray(st["seq_gather"]),
+                        jnp.asarray(st["pack_gather"]))
+            with _DISPATCH:
+                self.k_pages, self.v_pages, scales, logits = self._fused_fn(
+                    self.k_pages, self.v_pages, self._scales_in(), *args,
+                    t_bucket=t_bucket, s_bucket=s_bucket,
+                    tq_bucket=tq_bucket, pg_bucket=pg_bucket)
         self._set_scales(scales)
         emitted: dict[int, int] = {}
         if any(s.emits for s in seqs):
             # one device→host sync for the whole step
-            nxt = np.asarray(jnp.argmax(logits, -1))
-            lg = np.asarray(logits) if self.capture_logits else None
+            with _SYNC:
+                nxt = np.asarray(jnp.argmax(logits, -1))
+                lg = np.asarray(logits) if self.capture_logits else None
             for i, s in enumerate(seqs):
                 if s.emits:
                     emitted[s.req_id] = int(nxt[i])
@@ -1091,7 +1134,9 @@ class PagedTransformerExecutor:
         decode_items = plan.decode_items
         for it in plan.prefill_items:
             req = requests[it.req_id]
-            if self._extend(it.req_id, it.n_tokens) is None:
+            with _EXTEND:
+                tbl = self._extend(it.req_id, it.n_tokens)
+            if tbl is None:
                 deferred.add(it.req_id)   # out of KV blocks: defer & retry
                 continue
             chunk = req.tokens[req.prefilled:req.prefilled + it.n_tokens]
@@ -1100,22 +1145,24 @@ class PagedTransformerExecutor:
             table = self._table(it.req_id)
             self.n_dispatches += 1
             self.compile_keys.add(("chunk", n_tok))
-            with self._step_ctx():
+            with _DISPATCH, self._step_ctx():
                 self.k_pages, self.v_pages, scales, logits = self._chunk_fn(
                     self.k_pages, self.v_pages, self._scales_in(), toks,
                     jnp.int32(req.prefilled), table, self._stable(it.req_id),
                     jnp.int32(len(chunk)), n_tok=n_tok)
             self._set_scales(scales)
             if req.prefilled + it.n_tokens == req.prompt_len:
-                emitted[it.req_id] = int(jnp.argmax(logits))
-                if self.capture_logits:
-                    self.last_logits[it.req_id] = np.asarray(logits)
+                with _SYNC:
+                    emitted[it.req_id] = int(jnp.argmax(logits))
+                    if self.capture_logits:
+                        self.last_logits[it.req_id] = np.asarray(logits)
         ids = []
-        for it in decode_items:
-            if self._extend(it.req_id, 1) is None:
-                deferred.add(it.req_id)
-                continue
-            ids.append(it.req_id)
+        with _EXTEND:
+            for it in decode_items:
+                if self._extend(it.req_id, 1) is None:
+                    deferred.add(it.req_id)
+                    continue
+                ids.append(it.req_id)
         if ids:
             bsz = _bucket(len(ids), 4)
             toks, pos, tables, ctx = [], [], [], []
@@ -1138,15 +1185,16 @@ class PagedTransformerExecutor:
             stables += [stables[0] * 0] * pad
             self.n_dispatches += 1
             self.compile_keys.add(("decode", bsz))
-            with self._step_ctx():
+            with _DISPATCH, self._step_ctx():
                 self.k_pages, self.v_pages, scales, logits = self._decode_fn(
                     self.k_pages, self.v_pages, self._scales_in(),
                     jnp.asarray(toks, jnp.int32), jnp.asarray(pos, jnp.int32),
                     jnp.stack(tables), jnp.stack(stables),
                     jnp.asarray(ctx, jnp.int32), bsz=bsz)
             self._set_scales(scales)
-            nxt = np.asarray(jnp.argmax(logits, -1))
-            lg = np.asarray(logits) if self.capture_logits else None
+            with _SYNC:
+                nxt = np.asarray(jnp.argmax(logits, -1))
+                lg = np.asarray(logits) if self.capture_logits else None
             for i, rid in enumerate(ids):
                 emitted[rid] = int(nxt[i])
                 if lg is not None:
