@@ -23,7 +23,7 @@ import functools
 import math
 import time
 import weakref
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -133,15 +133,64 @@ def _ladder(n: int, lo: int) -> int:
     return b
 
 
-def scatter_rows(k_pools: tuple, v_pools: tuple, layer: int, k, v, pages,
-                 slots) -> tuple:
-    """Write token rows k, v (N, Hkv[, D]) at (pages, slots) of layer
-    ``layer``'s pools — (P, Hkv, D, page) K/V pages or (P, Hkv, page) scale
-    pages. Returns the updated (k_pools, v_pools)."""
+class RowDest(NamedTuple):
+    """Where a step's N new K/V rows land (DESIGN.md §3): the distinct
+    pages they touch, sorted and padded to a static bound with the pool's
+    page count (an id past the last page), each row's index into those
+    pages, and each row's slot."""
+    pages: jax.Array        # (U,)
+    inv: jax.Array          # (N,)
+    slots: jax.Array        # (N,)
+
+
+def pages_bound(n_rows: int, n_seqs: int, page_size: int,
+                num_pages: int) -> int:
+    """The most distinct pages ``n_rows`` rows of ``n_seqs`` sequences can
+    write: a run of n consecutive slots spans at most n // page_size + 2
+    pages, and padding rows add the trash page."""
+    return min(n_rows, num_pages, n_rows // page_size + 2 * n_seqs + 1)
+
+
+def row_dest(pages, slots, bound: int, num_pages: int) -> RowDest:
+    """The ``RowDest`` of rows at (pages, slots), both (N,); ``bound`` is at
+    least the number of distinct ``pages`` (``pages_bound``). Computed once
+    per step and shared by every layer's write."""
+    upages, inv = jnp.unique(pages, size=bound, fill_value=num_pages,
+                             return_inverse=True)
+    return RowDest(upages, inv.reshape(-1), slots)
+
+
+def table_dest(table, positions, page_size: int, num_pages: int,
+               valid=None) -> RowDest:
+    """The ``RowDest`` of a (B, T) block of rows: positions (B, T) global,
+    table (B, n_pages) page ids; rows with ``valid`` false go to the trash
+    page."""
+    b, t = positions.shape
+    pages = jnp.take_along_axis(table, positions // page_size, axis=1)
+    if valid is not None:
+        pages = jnp.where(valid, pages, 0)                    # → trash page
+    return row_dest(pages.reshape(-1), (positions % page_size).reshape(-1),
+                    pages_bound(b * t, b, page_size, num_pages), num_pages)
+
+
+def scatter_rows(k_pools: tuple, v_pools: tuple, layer: int, k, v,
+                 dest: RowDest) -> tuple:
+    """Write token rows k, v (N, Hkv[, D]) at ``dest`` in layer ``layer``'s
+    pools — (P, Hkv, D, page) K/V pages or (P, Hkv, page) scale pages.
+    Returns the updated (k_pools, v_pools).
+
+    The write goes page by page: gather the U touched pages, merge the rows
+    into that tile with the slot moved next to the page, and scatter the
+    tile back on dim 0, in place. A scatter straight at (page, slot) would
+    index two dims that are not adjacent, and XLA then relays the whole
+    pool around it, in and out, whatever the batch; here only the tile is
+    relaid. Padding entries of ``dest.pages`` lie past the pool: the gather
+    clips them and the scatter drops them."""
     def put(pools, rows):
-        idx = (pages, slice(None)) + (slice(None),) * (rows.ndim - 2) \
-            + (slots,)
-        new = pools[layer].at[idx].set(rows)
+        pool = pools[layer]
+        tile = jnp.moveaxis(pool.at[dest.pages].get(mode="clip"), -1, 1)
+        tile = tile.at[dest.inv, dest.slots].set(rows)
+        new = pool.at[dest.pages].set(jnp.moveaxis(tile, 1, -1), mode="drop")
         return pools[:layer] + (new,) + pools[layer + 1:]
     return put(k_pools, k), put(v_pools, v)
 
@@ -425,33 +474,20 @@ class PagedTransformerExecutor:
         v = constrain(v, (None, None, "kv_heads", None))
         return q, k, v
 
-    def _write_pages(self, k_pages, v_pages, scales, layer, k, v, table,
-                     stable, positions, valid=None):
-        """k, v: (B, T, Hkv, D); positions: (B, T) global; table/stable:
-        (B, n_pages) data/scale page ids. When quantized, values quantize
-        on scatter and their per-(token, kv-head) scales land in the scale
-        pages (DESIGN.md §14); ``scales`` is () in fp32 mode."""
-        b, t = positions.shape
-        page_ids = jnp.take_along_axis(
-            table, positions // self.page_size, axis=1)       # (B, T)
-        slots = positions % self.page_size
-        if valid is not None:
-            page_ids = jnp.where(valid, page_ids, 0)          # → trash page
-        flat_pg = page_ids.reshape(-1)
-        flat_sl = slots.reshape(-1)
+    def _write_pages(self, k_pages, v_pages, scales, layer, k, v, dests):
+        """Write k, v ([B,] T, Hkv, D) at ``dests``, the step's (data, scale)
+        ``RowDest`` pair, the scale one None in fp32 mode. When quantized,
+        values quantize on scatter and their per-(token, kv-head) scales
+        land in the scale pages (DESIGN.md §14); ``scales`` is () in fp32
+        mode."""
+        dest, sdest = dests
+        k = k.reshape(-1, *k.shape[-2:])
+        v = v.reshape(-1, *v.shape[-2:])
         if self.qspec is not None:
             k, ks = kvq.quantize_kv(k, self.qspec)
             v, vs = kvq.quantize_kv(v, self.qspec)
-            spage_ids = jnp.take_along_axis(
-                stable, positions // self.page_size, axis=1)
-            if valid is not None:
-                spage_ids = jnp.where(valid, spage_ids, 0)    # → trash scales
-            flat_sp = spage_ids.reshape(-1)
-            scales = scatter_rows(*scales, layer, ks.reshape(b * t, -1),
-                                  vs.reshape(b * t, -1), flat_sp, flat_sl)
-        k_pages, v_pages = scatter_rows(
-            k_pages, v_pages, layer, k.reshape(b * t, *k.shape[2:]),
-            v.reshape(b * t, *v.shape[2:]), flat_pg, flat_sl)
+            scales = scatter_rows(*scales, layer, ks, vs, sdest)
+        k_pages, v_pages = scatter_rows(k_pages, v_pages, layer, k, v, dest)
         return k_pages, v_pages, scales
 
     def _attend(self, q, k_pages, v_pages, scales, layer, table, stable,
@@ -473,14 +509,18 @@ class PagedTransformerExecutor:
         """Paged forward. ``n_layers`` truncates the stack (early-exit
         draft pass, DESIGN.md §18); None runs the full model."""
         cfg = self.cfg
+        # where the rows land, once for every layer (DESIGN.md §3)
+        dest = functools.partial(table_dest, positions=positions,
+                                 page_size=self.page_size,
+                                 num_pages=k_pages[0].shape[0], valid=valid)
+        dests = (dest(table), None if self.qspec is None else dest(stable))
         for l in range(cfg.n_layers if n_layers is None else n_layers):
             lp = jax.tree.map(lambda a: a[l], self.params["layers"])
             h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
             q, k, v = self._constrain_qkv(*attn_qkv(lp["attn"], h, positions,
                                                     cfg))
             k_pages, v_pages, scales = self._write_pages(
-                k_pages, v_pages, scales, l, k, v, table, stable, positions,
-                valid)
+                k_pages, v_pages, scales, l, k, v, dests)
             o = self._attend(q, k_pages, v_pages, scales, l, table, stable,
                              ctx_lens, positions[:, 0])
             x = x + o.reshape(*x.shape[:2], cfg.q_dim) @ lp["attn"]["wo"]
@@ -609,19 +649,6 @@ class PagedTransformerExecutor:
         return (k_pages, v_pages, scales, dstate, emitted, counts,
                 jnp.stack(accs))
 
-    def _scatter_packed(self, k_pages, v_pages, scales, layer, k, v,
-                        tok_pages, tok_slots, tok_spages):
-        """Packed-stream K/V scatter: k, v (T, Hkv, D) new rows. Quantizes
-        on scatter when a kv quant spec is active (DESIGN.md §14)."""
-        if self.qspec is not None:
-            k, ks = kvq.quantize_kv(k, self.qspec)
-            v, vs = kvq.quantize_kv(v, self.qspec)
-            scales = scatter_rows(*scales, layer, ks, vs, tok_spages,
-                                  tok_slots)
-        k_pages, v_pages = scatter_rows(k_pages, v_pages, layer, k, v,
-                                        tok_pages, tok_slots)
-        return k_pages, v_pages, scales
-
     def _fused_step(self, k_pages, v_pages, scales, tokens, positions,
                     tok_pages, tok_slots, tok_spages, tables, stables,
                     ctx_lens, q_starts, q_lens, pos0, last_idx, seq_gather,
@@ -638,59 +665,90 @@ class PagedTransformerExecutor:
         index maps for the batched attention backend. When quantized,
         tok_spages (T,)/stables (S, pg_bucket) carry the scale-page routing
         and ``scales`` is the (k_scales, v_scales) pair — () in fp32 mode.
-        Per layer: one K/V scatter for every sequence's writes, one
-        attention launch; at the top: one head projection over each
-        sequence's last-token hidden state. Returns (k_pages, v_pages,
-        scales, logits (S, vocab)).
+        Per layer: one K/V write for every sequence's rows, one attention
+        launch; at the top: one head projection over each sequence's
+        last-token hidden state. Returns (k_pages, v_pages, scales, logits
+        (S, vocab)).
+
+        The layer body is one nested jit, traced and lowered once per
+        compile key and called for every layer; XLA inlines the calls, so
+        the program is the unrolled stack, but obtaining a key costs one
+        layer's tracing, not ``n_layers``'.
         """
         cfg = self.cfg
         x = self._embed(tokens)[None]                     # (1, T, d)
-        pos2d = positions[None]
         # autotuned kernel tiling for this bucket (DESIGN.md §14), keyed by
         # the mesh shape too (§17) — single-device winners never silently
         # apply to sharded launches; install tilings before serving, the
         # jit cache keys on bucket, not tiling
         kb, tb = get_ragged_tiling(t_bucket, pg_bucket, mesh=self._mesh_key)
-        # each phase is a named scope, so the device ops it lowers to carry
-        # the phase in their metadata (read from a profiler trace)
+        # where the rows land, once for every layer (DESIGN.md §3)
+        n_pages = k_pages[0].shape[0]
+        dest = functools.partial(
+            row_dest, slots=tok_slots, num_pages=n_pages,
+            bound=pages_bound(t_bucket, s_bucket, self.page_size, n_pages))
+        dests = (dest(tok_pages),
+                 None if self.qspec is None else dest(tok_spages))
+        layer = jax.jit(functools.partial(
+            self._fused_layer, kb=kb, tb=tb, t_bucket=t_bucket,
+            s_bucket=s_bucket, tq_bucket=tq_bucket))
         for l in range(cfg.n_layers):
             lp = jax.tree.map(lambda a: a[l], self.params["layers"])
-            with jax.named_scope("qkv"):
-                h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
-                q, k, v = self._constrain_qkv(*attn_qkv(lp["attn"], h, pos2d,
-                                                        cfg))
-            with jax.named_scope("kv_scatter"):
-                k_pages, v_pages, scales = self._scatter_packed(
-                    k_pages, v_pages, scales, l, k[0], v[0], tok_pages,
-                    tok_slots, tok_spages)
-            with jax.named_scope("attention"):
-                if self._ragged_attn:
-                    if self.qspec is None:
-                        o = self._head_parallel(
-                            paged_attention_ragged_op, q[0],
-                            (k_pages[l], v_pages[l]), tables, ctx_lens,
-                            q_starts, q_lens, pos0, window=cfg.window,
-                            pages_per_block=kb, q_block=tb)
-                    else:
-                        o = self._head_parallel(
-                            paged_attention_ragged_quant_op, q[0],
-                            (k_pages[l], v_pages[l], scales[0][l],
-                             scales[1][l]), tables, stables, ctx_lens,
-                            q_starts, q_lens, pos0, window=cfg.window,
-                            pages_per_block=kb, q_block=tb)
-                else:
-                    qv = q[0][seq_gather]                 # (S, Tq, H, D)
-                    ov = self._attend(qv, k_pages, v_pages, scales, l,
-                                      tables, stables, ctx_lens, pos0)
-                    o = ov.reshape(s_bucket * tq_bucket,
-                                   *ov.shape[2:])[pack_gather]
-            with jax.named_scope("oproj_ffn"):
-                x = x + o.reshape(1, t_bucket, cfg.q_dim) @ lp["attn"]["wo"]
-                x = constrain(x, (None, None, "embed"))
-                x = self._layer_ffn(lp, x)
+            pools = (k_pages[l], v_pages[l]) + tuple(s[l] for s in scales)
+            x, pools = layer(lp, x, pools, dests, positions[None], tables,
+                             stables, ctx_lens, q_starts, q_lens, pos0,
+                             seq_gather, pack_gather)
+            k_pages, v_pages = (p[:l] + (new,) + p[l + 1:] for p, new in
+                                zip((k_pages, v_pages), pools[:2]))
+            scales = tuple(s[:l] + (new,) + s[l + 1:]
+                           for s, new in zip(scales, pools[2:]))
         with jax.named_scope("head"):
             h_last = x[0][last_idx]                       # (S, d)
             return k_pages, v_pages, scales, self._head(h_last)
+
+    def _fused_layer(self, lp, x, pools, dests, pos2d, tables, stables,
+                     ctx_lens, q_starts, q_lens, pos0, seq_gather,
+                     pack_gather, *, kb, tb, t_bucket, s_bucket, tq_bucket):
+        """One layer of ``_fused_step`` on its own pools, (k, v) and, when
+        quantized, (k_scales, v_scales). Returns (x, pools). Each phase is a
+        named scope, so the device ops it lowers to carry the phase in
+        their metadata (read from a profiler trace)."""
+        cfg = self.cfg
+        k_pages, v_pages = pools[:1], pools[1:2]
+        scales = tuple((p,) for p in pools[2:])
+        with jax.named_scope("qkv"):
+            h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
+            q, k, v = self._constrain_qkv(*attn_qkv(lp["attn"], h, pos2d,
+                                                    cfg))
+        with jax.named_scope("kv_scatter"):
+            k_pages, v_pages, scales = self._write_pages(
+                k_pages, v_pages, scales, 0, k[0], v[0], dests)
+        with jax.named_scope("attention"):
+            if self._ragged_attn:
+                if self.qspec is None:
+                    o = self._head_parallel(
+                        paged_attention_ragged_op, q[0],
+                        (k_pages[0], v_pages[0]), tables, ctx_lens,
+                        q_starts, q_lens, pos0, window=cfg.window,
+                        pages_per_block=kb, q_block=tb)
+                else:
+                    o = self._head_parallel(
+                        paged_attention_ragged_quant_op, q[0],
+                        (k_pages[0], v_pages[0], scales[0][0],
+                         scales[1][0]), tables, stables, ctx_lens,
+                        q_starts, q_lens, pos0, window=cfg.window,
+                        pages_per_block=kb, q_block=tb)
+            else:
+                qv = q[0][seq_gather]                     # (S, Tq, H, D)
+                ov = self._attend(qv, k_pages, v_pages, scales, 0, tables,
+                                  stables, ctx_lens, pos0)
+                o = ov.reshape(s_bucket * tq_bucket,
+                               *ov.shape[2:])[pack_gather]
+        with jax.named_scope("oproj_ffn"):
+            x = x + o.reshape(1, t_bucket, cfg.q_dim) @ lp["attn"]["wo"]
+            x = constrain(x, (None, None, "embed"))
+            x = self._layer_ffn(lp, x)
+        return x, k_pages + v_pages + tuple(s[0] for s in scales)
 
     # ------------------------------------------------------------------
 

@@ -43,7 +43,7 @@ from ..configs.base import ArchConfig
 from ..kernels.ops import paged_attention_op
 from ..models.layers import attn_qkv, mlp_apply
 from ..models.module import rmsnorm
-from .executor import copy_pages, scatter_rows
+from .executor import copy_pages, scatter_rows, table_dest
 
 
 class AcceptanceEWMA:
@@ -127,21 +127,6 @@ class TruncatedSelfDraft:
         pass
 
 
-def _draft_attend_write(dk, dv, layer, k, v, tables, positions, page_size,
-                        valid=None):
-    """Scatter a draft step's K/V into layer ``layer``'s draft pools (per
-    layer, (P, Hkv, D, page) like the target's) at (page, slot)."""
-    b, t = positions.shape
-    page_ids = jnp.take_along_axis(tables, positions // page_size, axis=1)
-    slots = positions % page_size
-    if valid is not None:
-        page_ids = jnp.where(valid, page_ids, 0)          # → trash page
-    flat_pg = page_ids.reshape(-1)
-    flat_sl = slots.reshape(-1)
-    return scatter_rows(dk, dv, layer, k.reshape(b * t, *k.shape[2:]),
-                        v.reshape(b * t, *v.shape[2:]), flat_pg, flat_sl)
-
-
 def draft_forward(cfg: ArchConfig, params, dk, dv, x, positions, tables,
                   ctx_lens, page_size: int, valid=None):
     """Dense-family forward over the draft's own paged KV (fp32, unsharded).
@@ -151,12 +136,13 @@ def draft_forward(cfg: ArchConfig, params, dk, dv, x, positions, tables,
     by the target allocator's global page ids.
     """
     assert cfg.family == "dense" and cfg.moe is None
+    dest = table_dest(tables, positions, page_size, dk[0].shape[0], valid)
     for l in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[l], params["layers"])
         h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
         q, k, v = attn_qkv(lp["attn"], h, positions, cfg)
-        dk, dv = _draft_attend_write(dk, dv, l, k, v, tables, positions,
-                                     page_size, valid)
+        dk, dv = scatter_rows(dk, dv, l, k.reshape(-1, *k.shape[2:]),
+                              v.reshape(-1, *v.shape[2:]), dest)
         o = paged_attention_op(q, dk[l], dv[l], tables, ctx_lens,
                                positions[:, 0], window=cfg.window)
         x = x + o.reshape(*x.shape[:2], cfg.q_dim) @ lp["attn"]["wo"]
